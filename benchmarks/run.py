@@ -128,6 +128,9 @@ def main() -> None:
                          "copy of the results to this directory")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         bench_dedup,
         bench_distributed,

@@ -38,8 +38,12 @@ Facts live in fixed-capacity padded buffers (JAX static shapes): a
 ``(capacity, arity)`` int32 array plus validity counts; empty slots hold
 ``EMPTY = -1``.  Join/dedup primitives are the jnp twins of the numpy
 host path in :mod:`repro.core.util` and are what the Pallas kernels
-accelerate.  The same code lowers on the 1-device CPU mesh (tests), the
-forced 4-device CPU mesh (CI matrix), and the multi-pod mesh (dry-run).
+accelerate.  Every sort is unstable (``stable=False``): no result
+depends on the order of equal keys, and the TPU compiler builds an
+unstable sort of 2^18 int32 keys several times faster than a stable
+one — sort compilation dominates the cold compile of a round.  The
+same code lowers on the 1-device CPU mesh (tests), the forced 4-device
+CPU mesh (CI matrix), and the multi-pod mesh (dry-run).
 """
 
 from __future__ import annotations
@@ -49,10 +53,9 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..obs import instant, publish_distributed, span
 from .compile import SRC_DELTA, SRC_OLD, PlanCache, compile_body, stats_bucket
 from .datalog import Program
@@ -105,21 +108,26 @@ def _hash_shard_np(keys: np.ndarray, n_shards: int) -> np.ndarray:
 # --------------------------------------------------------------------- #
 # jnp primitives (device twins of core.util; kernels/ accelerates these)
 # --------------------------------------------------------------------- #
-def sorted_member_jnp(a: jax.Array, b_sorted: jax.Array) -> jax.Array:
-    """Membership of a[i] in sorted b (EMPTY-padded b allowed at the end)."""
+def sorted_member_jnp(
+    a: jax.Array, b_sorted: jax.Array, a_sorted: bool = False
+) -> jax.Array:
+    """Membership of a[i] in sorted b (EMPTY-padded b allowed at the end);
+    ``a_sorted`` is accepted for parity with the kernel and unused."""
     idx = jnp.searchsorted(b_sorted, a)
     idx = jnp.minimum(idx, b_sorted.shape[0] - 1)
     return b_sorted[idx] == a
 
 
-def sorted_member_kernel(a: jax.Array, b_sorted: jax.Array) -> jax.Array:
+def sorted_member_kernel(
+    a: jax.Array, b_sorted: jax.Array, a_sorted: bool = False
+) -> jax.Array:
     """Pallas-kernel membership (``repro.kernels.sorted_member``) — the
     TPU device path for the dedup anti-join.  ``interpret`` is backend-
-    detected (interpret on CPU, compiled on TPU; override with
-    ``REPRO_PALLAS_INTERPRET`` — see ``repro.kernels.backend``)."""
+    detected (interpret on CPU, compiled on TPU — see
+    ``repro.kernels.backend``)."""
     from ..kernels import ops
 
-    return ops.member(a, b_sorted, interpret=None)
+    return ops.member(a, b_sorted, a_sorted=a_sorted, interpret=None)
 
 
 #: x64 is disabled by default in JAX, so packed fact keys live in int32:
@@ -148,19 +156,24 @@ def unpack_pairs(keys: jax.Array, arity: int) -> jax.Array:
 
 def dedup_against(
     new_keys: jax.Array, new_valid: jax.Array, old_keys_sorted: jax.Array,
-    member_fn=sorted_member_jnp,
+    member_fn=sorted_member_jnp, restrict_sorted: jax.Array | None = None,
 ) -> jax.Array:
-    """Valid-mask of new facts that are not already present in old."""
-    member = member_fn(new_keys, old_keys_sorted)
-    # first-occurrence within new: sort, compare neighbours, scatter back
+    """Valid-mask of new facts that are not already present in old (and,
+    with ``restrict_sorted``, that are present there).
+
+    Works on the sorted keys — first occurrences are neighbour compares,
+    and the membership probes arrive sorted — then scatters back."""
     masked = jnp.where(new_valid, new_keys, BIG)
-    order = jnp.argsort(masked)
-    ks = masked[order]
-    first_sorted = jnp.concatenate(
-        [jnp.ones((1,), bool), ks[1:] != ks[:-1]]
+    ks, order = jax.lax.sort(
+        (masked, jnp.arange(masked.shape[0], dtype=jnp.int32)),
+        num_keys=1, is_stable=False,
     )
-    first = jnp.zeros_like(first_sorted).at[order].set(first_sorted)
-    return new_valid & first & (~member)
+    keep = jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]])
+    # valid keys never equal BIG (packed ids stay below it)
+    keep = keep & (ks != BIG) & ~member_fn(ks, old_keys_sorted, a_sorted=True)
+    if restrict_sorted is not None:
+        keep = keep & member_fn(ks, restrict_sorted, a_sorted=True)
+    return jnp.zeros_like(keep).at[order].set(keep)
 
 
 def join_on_key(
@@ -180,7 +193,7 @@ def join_on_key(
     truncation (and regrow) instead of silently under-deriving.
     """
     r_sort_key = jnp.where(r_valid, r_keys, BIG)
-    order = jnp.argsort(r_sort_key)
+    order = jnp.argsort(r_sort_key, stable=False)
     r_keys_s = r_sort_key[order]
     r_payload_s = r_payload[order]
 
@@ -387,6 +400,23 @@ class DistributedEngine:
             out[pred] = (buf, cnt)
         return out
 
+    def _to_shards(self, x: np.ndarray) -> jax.Array:
+        """Place a host array whose leading axis is the shard axis onto
+        the mesh: each device receives its own slice, nothing is staged
+        on the first device."""
+        spec = P(self.axis, *([None] * (x.ndim - 1)))
+        return jax.device_put(x, NamedSharding(self.mesh, spec))
+
+    def _sharded_pair(self, routed: dict, pred: str) -> tuple:
+        """``(rows, cnt)`` on the mesh for ``pred`` — empty buffers when
+        the routed batch holds no rows of it."""
+        buf, cnt = routed.get(pred) or (
+            np.full((self.n_shards, self.capacity, self._arities[pred]), -1,
+                    np.int32),
+            np.zeros((self.n_shards,), np.int32),
+        )
+        return self._to_shards(buf), self._to_shards(cnt)
+
     @staticmethod
     def _check_const_range(pred: str, rows: np.ndarray) -> None:
         """Load-bearing for pack_pairs/BIG-sentinel correctness:
@@ -514,8 +544,9 @@ class DistributedEngine:
         # (and buffers stay bounded by n_shards x input)
         per = min(max((cap * factor) // n_shards, 1), cap)
         shard_of = jnp.where(valid, _hash_shard(keys, n_shards), n_shards)
-        # stable sort by destination; bucket i occupies slots [i*per,(i+1)*per)
-        order = jnp.argsort(shard_of, stable=True)
+        # sort by destination (order inside a bucket is free: a dropped row
+        # forces a retry); bucket i occupies slots [i*per,(i+1)*per)
+        order = jnp.argsort(shard_of, stable=False)
         rows_s = rows[order]
         shard_s = shard_of[order]
         idx = jnp.arange(cap)
@@ -680,7 +711,7 @@ class DistributedEngine:
         return [P(self.axis, None, None), P(self.axis)]
 
     def _shmap(self, body, in_specs, out_specs, donate_argnums=()):
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=tuple(in_specs),
@@ -697,12 +728,9 @@ class DistributedEngine:
         merge — NOT the fixpoint rounds, which retry the *same* inputs
         on exchange overflow and so must never donate).  Donation lets
         XLA reuse the old buffers for the outputs, so steady-state
-        maintenance allocates nothing; it is a no-op (with a warning)
-        on CPU, so only engage it on backends that honour it."""
-        from ..kernels.backend import backend_name
-
-        if backend_name() == "cpu":
-            return ()
+        maintenance allocates nothing.  Every backend honours it (the
+        CPU one included), so a donated input read afterwards fails in
+        the CPU tests exactly as it does on the chip."""
         return tuple(range(3 * len(self._preds)))
 
     def _merge_block(self, trows, tcnt, rows, valid, restrict=None):
@@ -712,14 +740,19 @@ class DistributedEngine:
         cap = trows.shape[0]
         keys = pack_pairs(rows)
         tvalid = jnp.arange(cap) < tcnt
-        tsorted = jnp.sort(jnp.where(tvalid, pack_pairs(trows), BIG))
-        fresh = dedup_against(keys, valid, tsorted, member_fn=self._member_fn)
+        tsorted = jnp.sort(
+            jnp.where(tvalid, pack_pairs(trows), BIG), stable=False
+        )
+        rsorted = None
         if restrict is not None:
             rrows, rcnt = restrict
             rsorted = jnp.sort(jnp.where(
                 jnp.arange(rrows.shape[0]) < rcnt, pack_pairs(rrows), BIG
-            ))
-            fresh = fresh & self._member_fn(keys, rsorted)
+            ), stable=False)
+        fresh = dedup_against(
+            keys, valid, tsorted, member_fn=self._member_fn,
+            restrict_sorted=rsorted,
+        )
         n_fresh = jnp.sum(fresh.astype(jnp.int32))
         overflow = jnp.maximum(tcnt + n_fresh - cap, 0)
         dest = tcnt + jnp.cumsum(fresh.astype(jnp.int32)) - 1
@@ -886,10 +919,12 @@ class DistributedEngine:
                 keys = jnp.where(slot, pack_pairs(rows), BIG)
                 dsorted = jnp.sort(jnp.where(
                     jnp.arange(drows.shape[0]) < dcnt, pack_pairs(drows), BIG
-                ))
+                ), stable=False)
                 keep = slot & ~member_fn(keys, dsorted)
                 n_keep = jnp.sum(keep.astype(jnp.int32))
-                perm = jnp.argsort(jnp.where(keep, idx, cap + idx))
+                perm = jnp.argsort(
+                    jnp.where(keep, idx, cap + idx), stable=False
+                )
                 nrows = jnp.where((idx < n_keep)[:, None], rows[perm], EMPTY)
                 out.extend([nrows[None], n_keep[None], n_keep[None]])
             return tuple(out)
@@ -1101,7 +1136,7 @@ class DistributedEngine:
         heads, body_preds = stratum_predicates(stratum)
         if sweep_lo is not None:
             for p in self._preds:
-                self._state[p][2] = sweep_lo[p]
+                self._state[p][2] = self._to_shards(sweep_lo[p])
         entry = naive_entry
         rounds = 0
         r0 = len(self.stats.per_round)
@@ -1239,9 +1274,8 @@ class DistributedEngine:
         )
         self._state = {}
         for p in preds:
-            buf, cnt = routed[p]
-            cnt = jnp.asarray(cnt)
-            self._state[p] = [jnp.asarray(buf), cnt, jnp.zeros_like(cnt)]
+            rows, cnt = self._sharded_pair(routed, p)
+            self._state[p] = [rows, cnt, jnp.zeros_like(cnt)]
 
     def materialise(self, dataset: dict[str, np.ndarray], max_rounds: int = 64):
         """Run rounds to fixpoint; returns per-predicate host arrays."""
@@ -1302,19 +1336,8 @@ class DistributedEngine:
             }
         )
         for p in self._preds:
-            if p in routed:
-                buf, cnt = routed[p]
-                cnt = jnp.asarray(cnt)
-                acc[p] = [jnp.asarray(buf), cnt, jnp.zeros_like(cnt)]
-            else:
-                acc[p] = [
-                    jnp.full(
-                        (self.n_shards, self.capacity, self._arities[p]),
-                        -1, jnp.int32,
-                    ),
-                    jnp.zeros((self.n_shards,), jnp.int32),
-                    jnp.zeros((self.n_shards,), jnp.int32),
-                ]
+            rows, cnt = self._sharded_pair(routed, p)
+            acc[p] = [rows, cnt, jnp.zeros_like(cnt)]
         return acc
 
     def _pull_acc(self, acc: dict) -> dict[str, np.ndarray]:
@@ -1340,20 +1363,7 @@ class DistributedEngine:
                 if np.asarray(r).shape[0]
             }
         )
-        out = {}
-        for p in self._preds:
-            if p in routed:
-                buf, cnt = routed[p]
-                out[p] = [jnp.asarray(buf), jnp.asarray(cnt)]
-            else:
-                out[p] = [
-                    jnp.full(
-                        (self.n_shards, self.capacity, self._arities[p]),
-                        -1, jnp.int32,
-                    ),
-                    jnp.zeros((self.n_shards,), jnp.int32),
-                ]
-        return out
+        return {p: list(self._sharded_pair(routed, p)) for p in self._preds}
 
     def _schedule_acc(self, rules, *, one_step: bool):
         """(rule, pivot) pairs for an accumulator round: the pivot reads
@@ -1575,7 +1585,10 @@ class DistributedEngine:
         as its delta (the ``sweep_lo`` watermark), so derived facts of
         earlier strata propagate without host-side seed bookkeeping."""
         with span("dist.insert") as sp:
-            sweep_lo = {p: self._state[p][1] for p in self._preds}
+            # a host copy: the merge below donates the device counts
+            sweep_lo = {
+                p: np.asarray(self._state[p][1]).copy() for p in self._preds
+            }
             self._merge_host_rows(adds, st, count_inserted=True)
             strata = (
                 stratify(self.program)

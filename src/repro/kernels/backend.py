@@ -7,11 +7,12 @@ same call sites compile the real Mosaic kernel as soon as a TPU/GPU
 backend is present — no code change needed to switch.
 
 The environment variable :data:`ENV_VAR` (``REPRO_PALLAS_INTERPRET``)
-overrides the detection in both directions: ``1/true/yes`` forces
-interpret mode (debugging a miscompile on hardware), ``0/false/no``
-forces compilation (exercising the Mosaic lowering under interpret-
-capable CI).  Resolution happens *outside* the jit'd kernels, so their
-caches are keyed on the resolved concrete bool.
+overrides the detection: ``0/false/no`` forces compilation (exercising
+the Mosaic lowering from a CPU process), ``1/true/yes`` asks for
+interpret mode and is honoured only on the CPU backend — on an
+accelerator it raises, so a run that looks like a device run always
+executes the compiled kernels.  Resolution happens *outside* the jit'd
+kernels, so their caches are keyed on the resolved concrete bool.
 """
 
 from __future__ import annotations
@@ -40,13 +41,20 @@ def default_interpret() -> bool:
     """True when kernels should run in interpret mode by default.
 
     Order: :data:`ENV_VAR` if set (anything unrecognised raises — a typo
-    silently flipping the execution path is the worst failure mode),
-    else backend detection (CPU -> interpret).
+    silently flipping the execution path is the worst failure mode, and
+    so does asking to interpret on an accelerator), else backend
+    detection (CPU -> interpret).
     """
     env = os.environ.get(ENV_VAR)
     if env is not None:
         val = env.strip().lower()
         if val in _TRUTHY:
+            backend = backend_name()
+            if backend != "cpu":
+                raise RuntimeError(
+                    f"{ENV_VAR}={env!r} asks for interpret mode on the "
+                    f"{backend} backend; kernels interpret only on CPU"
+                )
             return True
         if val in _FALSY:
             return False

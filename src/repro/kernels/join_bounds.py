@@ -7,16 +7,18 @@ rows in the key-sorted right side:
 
 A serial merge computes these with two pointers; on TPU we accumulate the
 counts blockwise over the sorted right side, with a three-way prune per
-(left-tile x right-block):
+(left-tile x right-block) on bounds scalar-prefetched into SMEM:
 
 * ``rmax <  lmin``  -> the whole block is below the tile: add BLOCK to
   both counters without comparing,
 * ``rmin >  lmax``  -> the whole block is above: skip entirely,
-* otherwise        -> one broadcast compare (VPU).
+* otherwise        -> an all-pairs compare-count on the VPU
+  (:func:`repro.kernels.tiles.fold_lanes`).
 
-For sorted inputs only O(1) blocks per tile take the compare path, so the
-work is O(n + m) with machine-width parallelism — this is the paper's
-merge retimed for a vector unit.
+Operands are ``(rows, 128)`` int32 tiles.  For sorted inputs only O(1)
+blocks per tile take the compare path, so the work is O(n + m) with
+machine-width parallelism — this is the paper's merge retimed for a
+vector unit.
 """
 
 from __future__ import annotations
@@ -26,40 +28,45 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .backend import resolve_interpret
+from .tiles import LANES, block_rows, fold_lanes, to_rows
 
-DEFAULT_BLOCK_L = 512
+DEFAULT_BLOCK_L = 1024
 DEFAULT_BLOCK_R = 1024
-_SENTINEL = jnp.iinfo(jnp.int32).max
 
 
-def _bounds_kernel(l_ref, r_ref, lo_ref, hi_ref, *, block_r: int):
-    j = pl.program_id(1)
+def _bounds_kernel(
+    lmin_ref, lmax_ref, rmin_ref, rmax_ref, l_ref, r_ref, lo_ref, hi_ref
+):
+    i, j = pl.program_id(0), pl.program_id(1)
+    lmin, lmax = lmin_ref[i], lmax_ref[i]
+    rmin, rmax = rmin_ref[j], rmax_ref[j]
 
     @pl.when(j == 0)
     def _init():
         lo_ref[...] = jnp.zeros_like(lo_ref)
         hi_ref[...] = jnp.zeros_like(hi_ref)
 
-    l = l_ref[...]
-    r = r_ref[...]
-    rmin, rmax = r[0], r[-1]
-    lmin, lmax = jnp.min(l), jnp.max(l)
-
     @pl.when(rmax < lmin)
     def _all_below():
-        lo_ref[...] += block_r
-        hi_ref[...] += block_r
+        n = r_ref.shape[0] * LANES
+        lo_ref[...] += n
+        hi_ref[...] += n
 
     @pl.when(jnp.logical_and(rmax >= lmin, rmin <= lmax))
     def _compare():
-        lo_ref[...] += jnp.sum(
-            (r[None, :] < l[:, None]).astype(jnp.int32), axis=1
+        l = l_ref[...]
+        lo, hi = fold_lanes(
+            (r_ref,), l.shape, (jnp.zeros_like(l), jnp.zeros_like(l)),
+            lambda acc, r: (
+                acc[0] + (r < l).astype(jnp.int32),
+                acc[1] + (r <= l).astype(jnp.int32),
+            ),
         )
-        hi_ref[...] += jnp.sum(
-            (r[None, :] <= l[:, None]).astype(jnp.int32), axis=1
-        )
+        lo_ref[...] += lo
+        hi_ref[...] += hi
 
 
 def join_bounds(
@@ -71,26 +78,25 @@ def join_bounds(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Return (lo, hi) spans of each left key in the sorted right keys.
+    Block sizes round up to whole ``(8, 128)`` tiles.
 
     ``interpret=None`` resolves per backend/env outside the jit."""
     return _join_bounds_jit(
         l_keys,
         r_sorted,
-        block_l=block_l,
-        block_r=block_r,
+        rows_l=block_rows(block_l),
+        rows_r=block_rows(block_r),
         interpret=resolve_interpret(interpret),
     )
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_l", "block_r", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("rows_l", "rows_r", "interpret"))
 def _join_bounds_jit(
     l_keys: jax.Array,
     r_sorted: jax.Array,
     *,
-    block_l: int,
-    block_r: int,
+    rows_l: int,
+    rows_r: int,
     interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     n, m = l_keys.shape[0], r_sorted.shape[0]
@@ -100,28 +106,35 @@ def _join_bounds_jit(
     if m == 0:
         z = jnp.zeros((n,), dtype=jnp.int32)
         return z, z
-    n_pad = -n % block_l
-    m_pad = -m % block_r
-    l_p = jnp.pad(l_keys.astype(jnp.int32), (0, n_pad), constant_values=_SENTINEL)
-    r_p = jnp.pad(
-        r_sorted.astype(jnp.int32), (0, m_pad), constant_values=_SENTINEL
-    )
-    grid = (l_p.shape[0] // block_l, r_p.shape[0] // block_r)
+    bl, br = rows_l * LANES, rows_r * LANES
+    n_p, m_p = -(-n // bl) * bl, -(-m // br) * br
+    l_p = to_rows(l_keys, n_p)
+    r_p = to_rows(r_sorted, m_p)
+    l_tiles = l_p.reshape(n_p // bl, bl)
+    r_blocks = r_p.reshape(m_p // br, br)
+    tile = pl.BlockSpec((rows_l, LANES), lambda i, j, *_: (i, 0))
     lo, hi = pl.pallas_call(
-        functools.partial(_bounds_kernel, block_r=block_r),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_l,), lambda i, j: (i,)),
-            pl.BlockSpec((block_r,), lambda i, j: (j,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_l,), lambda i, j: (i,)),
-            pl.BlockSpec((block_l,), lambda i, j: (i,)),
-        ],
+        _bounds_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_p // bl, m_p // br),
+            in_specs=[
+                tile,
+                pl.BlockSpec((rows_r, LANES), lambda i, j, *_: (j, 0)),
+            ],
+            out_specs=[tile, tile],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((l_p.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((l_p.shape[0],), jnp.int32),
+            jax.ShapeDtypeStruct(l_p.shape, jnp.int32),
+            jax.ShapeDtypeStruct(l_p.shape, jnp.int32),
         ],
         interpret=interpret,
-    )(l_p, r_p)
-    return lo[:n], hi[:n]
+    )(
+        l_tiles.min(axis=1),
+        l_tiles.max(axis=1),
+        r_blocks[:, 0],
+        r_blocks[:, -1],
+        l_p,
+        r_p,
+    )
+    return lo.reshape(n_p)[:n], hi.reshape(n_p)[:n]

@@ -93,13 +93,18 @@ def _blocks_for(kernel: str, n: int, interpret, blocks: dict) -> dict:
     return get_blocks(kernel, "int32", n, interpret=interpret)
 
 
-def member(a, b_sorted, *, interpret: bool | None = None, **blocks) -> jax.Array:
-    """``out[i] = a[i] in b_sorted`` (semi-join filter)."""
+def member(a, b_sorted, *, a_sorted: bool = False,
+           interpret: bool | None = None, **blocks) -> jax.Array:
+    """``out[i] = a[i] in b_sorted`` (semi-join filter); ``a_sorted``
+    promises ascending probes (see :func:`.sorted_member.sorted_member`)."""
     a = jnp.asarray(a)
     _metered("member", a.size, a)
     interpret = resolve_interpret(interpret)
     blocks = _blocks_for("sorted_member", a.size, interpret, blocks)
-    return _sorted_member(a, jnp.asarray(b_sorted), interpret=interpret, **blocks)
+    return _sorted_member(
+        a, jnp.asarray(b_sorted), a_sorted=a_sorted, interpret=interpret,
+        **blocks,
+    )
 
 
 def anti_join_mask(new, old_sorted, *, interpret: bool | None = None, **blocks):

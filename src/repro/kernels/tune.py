@@ -11,8 +11,13 @@ timing sweep:
   disk cache stays small,
 * **sweep** — each candidate block assignment is timed best-of-3 on
   synthetic sorted operands of the bucket size (``block_until_ready``
-  so device time is measured, not dispatch), and the fastest wins,
-* **cache** — winners persist to a JSON file (:func:`cache_path`;
+  so device time is measured, not dispatch), and the fastest wins.  The
+  sweep runs eagerly, on a thread of its own, even when the lookup
+  happens while a caller is being traced.  A sweep in which no
+  candidate runs raises: there is no
+  silent fallback to untested defaults on hardware,
+* **cache** — winners persist to a JSON file (:func:`cache_path`,
+  ``pallas_tune.json`` in the checkout's fixed cache directory;
   override with ``REPRO_TUNE_CACHE``) keyed by
   ``kernel|dtype|bucket|backend``.
 
@@ -34,7 +39,9 @@ from __future__ import annotations
 import json
 import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 
+from ..compile_cache import CACHE_DIR
 from ..obs import get_registry, span
 from .backend import backend_name, resolve_interpret
 
@@ -47,40 +54,33 @@ __all__ = [
     "size_bucket",
 ]
 
-CACHE_VERSION = 1
+CACHE_VERSION = 2
 
-#: hand-tuned fallbacks (v5e-sized VMEM tiles) — returned without a
-#: sweep in interpret mode and for kernels with no registered runner
+#: hand-tuned defaults, whole (8, 128) int32 tiles — returned without a
+#: sweep in interpret mode
 DEFAULTS: dict[str, dict[str, int]] = {
-    "sorted_member": {"block_a": 512, "block_b": 1024},
-    "join_bounds": {"block_l": 512, "block_r": 1024},
+    "sorted_member": {"block_a": 1024, "block_b": 1024},
+    "join_bounds": {"block_l": 1024, "block_r": 1024},
     "rle_expand": {"block_out": 1024},
 }
 
 #: candidate assignments swept per kernel (defaults always included)
 CANDIDATES: dict[str, list[dict[str, int]]] = {
     "sorted_member": [
-        {"block_a": a, "block_b": b}
-        for a in (256, 512, 1024)
-        for b in (512, 1024, 2048)
+        {"block_a": a, "block_b": b} for a in (1024, 2048) for b in (1024, 2048)
     ],
     "join_bounds": [
-        {"block_l": a, "block_r": b}
-        for a in (256, 512, 1024)
-        for b in (512, 1024, 2048)
+        {"block_l": a, "block_r": b} for a in (1024, 2048) for b in (1024, 2048)
     ],
-    "rle_expand": [{"block_out": b} for b in (512, 1024, 2048, 4096)],
+    "rle_expand": [{"block_out": b} for b in (1024, 2048, 4096)],
 }
 
 _cache: dict[str, dict[str, int]] | None = None  # in-process mirror
 
 
 def cache_path() -> str:
-    env = os.environ.get("REPRO_TUNE_CACHE")
-    if env:
-        return env
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "repro", "pallas_tune.json"
+    return os.environ.get("REPRO_TUNE_CACHE") or os.path.join(
+        CACHE_DIR, "pallas_tune.json"
     )
 
 
@@ -156,7 +156,7 @@ def _runner(kernel: str, bucket: int, blocks: dict[str, int], interpret: bool):
 
         a = jnp.arange(bucket, dtype=jnp.int32) * 3
         b = jnp.arange(bucket, dtype=jnp.int32) * 2
-        out = sorted_member(a, b, interpret=interpret, **blocks)
+        out = sorted_member(a, b, a_sorted=True, interpret=interpret, **blocks)
     elif kernel == "join_bounds":
         from .join_bounds import join_bounds
 
@@ -178,13 +178,22 @@ def _runner(kernel: str, bucket: int, blocks: dict[str, int], interpret: bool):
 
 
 def _sweep(kernel: str, bucket: int, interpret: bool) -> dict[str, int]:
+    # a lookup can come while a caller is being traced (the sharded round
+    # calls the kernel inside its shard_map); JAX keeps trace state per
+    # thread, so the timing runs eagerly on a thread of its own
+    with ThreadPoolExecutor(1) as ex:
+        return ex.submit(_sweep_eager, kernel, bucket, interpret).result()
+
+
+def _sweep_eager(kernel: str, bucket: int, interpret: bool) -> dict[str, int]:
     with span(
         "kernels.tune.sweep",
         kernel=kernel,
         bucket=bucket,
         candidates=len(CANDIDATES[kernel]),
     ) as sp:
-        best_blocks, best_t = DEFAULTS[kernel], float("inf")
+        best_blocks, best_t = None, float("inf")
+        errors = []
         for blocks in CANDIDATES[kernel]:
             try:
                 _runner(kernel, bucket, blocks, interpret)  # compile + warm
@@ -193,10 +202,17 @@ def _sweep(kernel: str, bucket: int, interpret: bool) -> dict[str, int]:
                     t0 = time.perf_counter()
                     _runner(kernel, bucket, blocks, interpret)
                     t = min(t, time.perf_counter() - t0)
-            except Exception:  # noqa: BLE001 — an invalid tiling just loses
+            except Exception as e:  # noqa: BLE001 — an invalid tiling just loses
+                first_line = (str(e).splitlines() or [""])[0]
+                errors.append(f"{blocks}: {type(e).__name__}: {first_line}")
                 continue
             if t < best_t:
                 best_blocks, best_t = blocks, t
+        if best_blocks is None:
+            raise RuntimeError(
+                f"no {kernel} tiling runs on the {backend_name()} backend "
+                f"(bucket {bucket}): " + "; ".join(errors)
+            )
         sp.set(best=str(dict(best_blocks)), best_s=best_t)
     return dict(best_blocks)
 

@@ -23,7 +23,6 @@ import traceback  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from ..compat import set_mesh  # noqa: E402
 from ..configs import SHAPES, get_config, list_configs  # noqa: E402
 from ..models.model import abstract_params, input_specs  # noqa: E402
 from ..models import transformer  # noqa: E402
@@ -71,7 +70,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         return param_shardings(tree, mesh, strategy=strategy)
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params_abs = abstract_params(cfg)
         if shape.kind == "train":
             state_abs = {

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import set_mesh
 from ..configs import get_config
 from ..models.model import Model
 from .mesh import make_host_mesh
@@ -40,7 +39,7 @@ def main(argv=None):
     set_policy_from_mesh(mesh)
     model = Model(cfg)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(args.seed))
         max_len = args.prompt_len + args.gen_len
         cache = model.init_cache(args.batch, max_len)

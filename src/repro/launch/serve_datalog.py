@@ -459,8 +459,9 @@ def _main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--no-result-cache", action="store_true")
     ap.add_argument("--pallas", action="store_true",
-                    help="route constant lookups through the Pallas kernel "
-                         "(interpret mode off-TPU)")
+                    help="route constant lookups, and the sharded engine's "
+                         "dedup membership, through the Pallas kernels "
+                         "(interpret mode on CPU)")
     ap.add_argument("--live", action="store_true",
                     help="serve updates interleaved with queries through "
                          "the incremental maintenance subsystem")
@@ -681,25 +682,28 @@ def _main(argv=None):
                 default=0,
             )
             cap = max(1 << 10, 1 << int(np.ceil(np.log2(max(2 * biggest, 2)))))
-        dist = DistributedEngine(dprog, mesh, capacity=cap)
+        dist = DistributedEngine(
+            dprog, mesh, capacity=cap, use_pallas_kernels=args.pallas
+        )
         t0 = time.perf_counter()
         # seed from the *restored* explicit set when the host store came
         # back from a checkpoint — the generator dataset no longer
         # reflects prior sessions' WAL batches and the final
         # differential check would flag a phantom mismatch
         dist.materialise(inc.explicit if inc is not None else dataset)
+        t_dist = time.perf_counter() - t0
         ds = dist.stats
         report.emit(
             "distributed",
-            f"{mesh.shape['data']} shard(s), {dist.rounds} "
-            f"rounds over {ds.n_strata} strata in "
-            f"{time.perf_counter() - t0:.2f}s; "
+            f"{mesh.shape['data']} shard(s) at capacity {cap}, {dist.rounds} "
+            f"rounds over {ds.n_strata} strata in {t_dist:.2f}s; "
             f"{ds.n_rule_applications} rule applications "
             f"({ds.rule_applications_skipped} skipped), "
             f"{ds.rows_joined} rows joined, {ds.exchanges} exchanges "
             f"({ds.exchanges_skipped} elided by planner keys, "
             f"{ds.exchange_regrows} regrows)",
-            get_registry().snapshot("dist."),
+            {**get_registry().snapshot("dist."), "seconds": t_dist,
+             "capacity": cap, "shards": mesh.shape["data"]},
         )
         if not dist_complete:
             report.emit(
@@ -868,7 +872,9 @@ def _main(argv=None):
             f"{int(inc_snap.get('inc.n_inserted', 0))} inserted facts, "
             f"{int(inc_snap.get('inc.n_rederived', 0))} rederived; "
             f"{qe.stale_evictions} stale cache entries evicted",
-            {**inc_snap, "stale_evictions": qe.stale_evictions},
+            {**inc_snap, "stale_evictions": qe.stale_evictions,
+             "apply_batches": len(apply_lat),
+             "seconds": float(np.sum(apply_lat))},
         )
         usage = inc.mu_usage()
         reg.gauge("gc.nodes").set(usage.n_nodes)
@@ -915,7 +921,8 @@ def _main(argv=None):
                 f"p99={np.percentile(dl_ms, 99):.2f}ms "
                 f"(last batch: {ds.n_overdeleted} overdeleted, "
                 f"{ds.n_rederived} rederived, {ds.n_inserted} inserted)",
-                reg.snapshot("dist."),
+                {**reg.snapshot("dist."), "apply_batches": len(dist_lat),
+                 "seconds": float(np.sum(dist_lat))},
             )
             if dist_complete:
                 try:
@@ -1093,8 +1100,10 @@ def main(argv=None):
     # --trace-out enables the process tracer and the provenance flags
     # enable the journal; restore both on every exit path so in-process
     # callers (tests, drivers) see no state leak
+    from ..compile_cache import enable_compile_cache
     from ..obs.provenance import get_journal
 
+    enable_compile_cache()
     tr = get_tracer()
     was_enabled = tr.enabled
     journal = get_journal()

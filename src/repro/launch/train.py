@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import set_mesh
 from ..configs import get_config
 from ..data import DataConfig, SyntheticCorpus, TokenStream, linearise_materialisation
 from ..optim import AdamWConfig
@@ -91,7 +90,7 @@ def main(argv=None):
         else SyntheticCorpus(data_cfg)
     )
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_train_state(jax.random.PRNGKey(args.seed), cfg, train_cfg)
         step_fn = jax.jit(make_train_step(cfg, train_cfg))
 

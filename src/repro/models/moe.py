@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from .layers import dense_init
 from . import sharding_policy
 from .sharding_policy import constrain
@@ -240,7 +239,7 @@ def _moe_ep_shardmap(params, x, cfg, policy):
         y = jax.lax.psum(y, model_axis)
         return y.reshape(b_loc, s, d), aux
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         block,
         in_specs=(x_spec, router_spec, expert_spec, expert_spec, expert_spec),
         out_specs=(out_spec, aux_spec),

@@ -226,6 +226,24 @@ class TestBackendResolution:
         with pytest.raises(ValueError, match="REPRO_PALLAS_INTERPRET"):
             backend.resolve_interpret(None)
 
+    @pytest.mark.parametrize("val", ["1", "true"])
+    def test_env_interpret_refused_off_cpu(self, monkeypatch, val):
+        # an accelerator never interprets: the flag raises, it does not
+        # quietly run the emulator under a device run's name
+        monkeypatch.setattr(backend, "backend_name", lambda: "tpu")
+        monkeypatch.setenv(backend.ENV_VAR, val)
+        with pytest.raises(RuntimeError, match="interpret only on CPU"):
+            backend.resolve_interpret(None)
+        monkeypatch.setenv(backend.ENV_VAR, "0")
+        assert backend.resolve_interpret(None) is False
+        monkeypatch.delenv(backend.ENV_VAR)
+        assert backend.resolve_interpret(None) is False
+
+
+def _stub_runner(kernel, bucket, blocks, interpret):
+    """Stands in for a kernel launch: every tiling runs (CPU cannot
+    compile Mosaic kernels, so the real runner always fails here)."""
+
 
 class TestTuneCache:
     @pytest.fixture(autouse=True)
@@ -234,6 +252,20 @@ class TestTuneCache:
         tune._cache = None
         yield
         tune._cache = None
+
+    def test_default_cache_lives_in_the_checkout(self, monkeypatch):
+        from repro.compile_cache import CACHE_DIR
+
+        monkeypatch.delenv("REPRO_TUNE_CACHE")
+        assert tune.cache_path() == os.path.join(CACHE_DIR, "pallas_tune.json")
+        assert not tune.cache_path().startswith(os.path.expanduser("~") + "/.")
+
+    def test_sweep_raises_when_no_tiling_runs(self):
+        # compiled (non-interpret) kernels cannot run on the CPU backend:
+        # the sweep must say so instead of returning untested defaults
+        with pytest.raises(RuntimeError, match="no rle_expand tiling runs"):
+            tune.get_blocks("rle_expand", n=300, interpret=False)
+        assert not os.path.exists(tune.cache_path())
 
     def test_interpret_mode_returns_defaults_without_cache(self):
         reg = get_registry()
@@ -244,9 +276,10 @@ class TestTuneCache:
         snap = reg.snapshot("kernels.tune.")
         assert snap["kernels.tune.defaults"] == 1
 
-    def test_sweep_writes_cache_then_hits(self):
+    def test_sweep_writes_cache_then_hits(self, monkeypatch):
         import json
 
+        monkeypatch.setattr(tune, "_runner", _stub_runner)
         reg = get_registry()
         reg.reset("kernels.tune.")
         b1 = tune.get_blocks("rle_expand", n=300, interpret=False)
@@ -261,9 +294,40 @@ class TestTuneCache:
         key = f"rle_expand|int32|{tune.size_bucket(300)}|cpu"
         assert raw["entries"][key] == b1
 
-    def test_version_mismatch_discards(self):
+    def test_sweep_runs_eagerly_inside_a_trace(self, monkeypatch):
+        # the sharded round looks its blocks up while shard_map traces it;
+        # the sweep still launches (here: interpret-mode) kernels for real
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import Mesh
+        from jax.sharding import PartitionSpec as P
+
+        runner, ran = tune._runner, []
+
+        def run_interpreted(kernel, bucket, blocks, interpret):
+            runner(kernel, bucket, blocks, True)
+            ran.append(blocks)
+
+        monkeypatch.setattr(tune, "_runner", run_interpreted)
+        seen = {}
+
+        def body(x):
+            seen.update(tune.get_blocks("sorted_member", n=300, interpret=False))
+            return x
+
+        mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+            check_vma=False,
+        ))(jnp.arange(8))
+        assert seen in tune.CANDIDATES["sorted_member"]
+        # every candidate: one warm-up launch and three timed ones
+        assert len(ran) == 4 * len(tune.CANDIDATES["sorted_member"])
+
+    def test_version_mismatch_discards(self, monkeypatch):
         import json
 
+        monkeypatch.setattr(tune, "_runner", _stub_runner)
         tune.get_blocks("rle_expand", n=300, interpret=False)
         raw = json.load(open(tune.cache_path()))
         raw["version"] = tune.CACHE_VERSION + 1

@@ -15,8 +15,6 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import Mesh
 
-from repro.compat import set_mesh
-
 from repro.configs import get_config
 from repro.models import moe
 from repro.models.sharding_policy import clear_policy, set_policy_from_mesh
@@ -36,7 +34,7 @@ y_ref, aux_ref = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg))(params, x)
 
 mesh = Mesh(np.asarray(jax.devices()).reshape(2, 2), ("data", "model"))
 set_policy_from_mesh(mesh)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_ep, aux_ep = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg))(params, x)
 
 np.testing.assert_allclose(
@@ -48,7 +46,7 @@ np.testing.assert_allclose(
 np.testing.assert_allclose(float(aux_ref), float(aux_ep), rtol=5e-2)
 
 # gradient flows through the EP path
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     def loss(p):
         y, aux = moe.moe_apply(p, x, cfg)
         return jnp.sum(y.astype(jnp.float32) ** 2) + aux

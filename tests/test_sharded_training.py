@@ -13,8 +13,6 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import Mesh
 
-from repro.compat import set_mesh
-
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticCorpus
 from repro.launch.sharding import batch_shardings, state_shardings
@@ -39,7 +37,7 @@ def run(mesh=None):
             losses.append(float(m["loss"]))
         return losses
     set_policy_from_mesh(mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_train_state(jax.random.PRNGKey(0), cfg, tcfg)
         st_sh = state_shardings(state, mesh)
         state = jax.tree_util.tree_map(jax.device_put, state, st_sh)
