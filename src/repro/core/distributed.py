@@ -91,6 +91,9 @@ class DistributedStats(MaterialisationStats):
     n_rederived: int = 0
     n_deleted: int = 0
     n_inserted: int = 0
+    #: device->host reads (counts, delta bounds, round scalars, result
+    #: buffers), each counted once by ``DistributedEngine._fetch``
+    host_syncs: int = 0
 
 
 def _hash_shard(keys: jax.Array, n_shards: int) -> jax.Array:
@@ -436,10 +439,23 @@ class DistributedEngine:
             out.extend(self._state[p])
         return out
 
+    def _fetch(self, x) -> np.ndarray:
+        """Read one device array to the host.  Every device->host read
+        of the engine goes through here, and is counted in
+        ``stats.host_syncs``."""
+        self.stats.host_syncs += 1
+        return np.asarray(x)
+
     def _delta_count(self, pred: str) -> int:
         _, cnt, lo = self._state[pred]
-        return int((np.asarray(cnt) - np.asarray(lo)).sum())
+        return int((self._fetch(cnt) - self._fetch(lo)).sum())
 
+    def _any_delta(self, preds) -> bool:
+        """True when some predicate of ``preds`` holds a delta."""
+        with span("dist.sync"):
+            return any(
+                self._delta_count(p) > 0 for p in preds if p in self._state
+            )
 
     # -------------------------------------------------------------- #
     # planning
@@ -607,9 +623,10 @@ class DistributedEngine:
             if self.n_shards > 1 and not (
                 self.planner_exchange_keys and self._side_aligned(step.atom, key)
             ):
-                rows, valid, d = self._exchange(
-                    rows, valid, factor, keys=rows[:, vars_.index(key)]
-                )
+                with jax.named_scope("exchange"):
+                    rows, valid, d = self._exchange(
+                        rows, valid, factor, keys=rows[:, vars_.index(key)]
+                    )
                 dropped = dropped + d
             sides.append((rows, valid, vars_))
         (ra, va, va_vars), (rb, vb, vb_vars) = sides
@@ -710,7 +727,11 @@ class DistributedEngine:
     def _spec2(self):
         return [P(self.axis, None, None), P(self.axis)]
 
-    def _shmap(self, body, in_specs, out_specs, donate_argnums=()):
+    def _shmap(self, kind, body, in_specs, out_specs, donate_argnums=()):
+        """The jitted ``shard_map`` of ``body``; its program is named
+        ``jit_body_<kind>`` (``round``, ``acc_round``, ``delete``,
+        ``merge``) in traces and compiler dumps."""
+        body.__name__ = body.__qualname__ = f"body_{kind}"
         return jax.jit(jax.shard_map(
             body,
             mesh=self.mesh,
@@ -749,10 +770,11 @@ class DistributedEngine:
             rsorted = jnp.sort(jnp.where(
                 jnp.arange(rrows.shape[0]) < rcnt, pack_pairs(rrows), BIG
             ), stable=False)
-        fresh = dedup_against(
-            keys, valid, tsorted, member_fn=self._member_fn,
-            restrict_sorted=rsorted,
-        )
+        with jax.named_scope("dedup"):
+            fresh = dedup_against(
+                keys, valid, tsorted, member_fn=self._member_fn,
+                restrict_sorted=rsorted,
+            )
         n_fresh = jnp.sum(fresh.astype(jnp.int32))
         overflow = jnp.maximum(tcnt + n_fresh - cap, 0)
         dest = tcnt + jnp.cumsum(fresh.astype(jnp.int32)) - 1
@@ -831,10 +853,14 @@ class DistributedEngine:
 
             dropped = jnp.zeros((), jnp.int32)
             joined = jnp.zeros((), jnp.int32)
-            for rule, _pivot, plan in pairs:
-                d, j = self._trace_pair(rule, plan, part, emit, factor)
-                dropped = dropped + d
-                joined = joined + j
+            # phase scopes name the ops in traces: join (one r<rule id>
+            # sub-scope a pair), exchange, merge and, inside it, dedup
+            with jax.named_scope("join"):
+                for rule, _pivot, plan in pairs:
+                    with jax.named_scope(f"r{self._rule_ids.get(rule, -1)}"):
+                        d, j = self._trace_pair(rule, plan, part, emit, factor)
+                    dropped = dropped + d
+                    joined = joined + j
 
             new_flat = []
             total_new = jnp.zeros((), jnp.int32)
@@ -857,12 +883,14 @@ class DistributedEngine:
                 if self.n_shards > 1 and not (
                     self.planner_exchange_keys and aligned
                 ):
-                    rows, valid, d = self._exchange(rows, valid, factor)
+                    with jax.named_scope("exchange"):
+                        rows, valid, d = self._exchange(rows, valid, factor)
                     dropped = dropped + d
-                nrows, ncnt, n_fresh, of = self._merge_block(
-                    trows, tcnt, rows, valid,
-                    restrict=restrict.get(pred) if use_restrict else None,
-                )
+                with jax.named_scope("merge"):
+                    nrows, ncnt, n_fresh, of = self._merge_block(
+                        trows, tcnt, rows, valid,
+                        restrict=restrict.get(pred) if use_restrict else None,
+                    )
                 total_new = total_new + n_fresh
                 overflow = overflow + of
                 new_flat.extend([nrows[None], ncnt[None], tcnt[None]])
@@ -891,7 +919,10 @@ class DistributedEngine:
             out_specs.extend(self._spec3())
         out_specs.extend([P(), P(), P(), P()])
         n_ex, n_sk = self._static_exchange_counts(pairs)
-        return _Variant(self._shmap(body, in_specs, out_specs), n_ex, n_sk)
+        kind = "acc_round" if acc_mode else "round"
+        return _Variant(
+            self._shmap(kind, body, in_specs, out_specs), n_ex, n_sk
+        )
 
     def _build_delete(self):
         """Per-shard deletion: drop routed rows from every predicate's
@@ -937,7 +968,9 @@ class DistributedEngine:
         out_specs: list = []
         for _ in preds:
             out_specs.extend(self._spec3())
-        return _Variant(self._shmap(body, in_specs, out_specs), 0, 0)
+        return _Variant(
+            self._shmap("delete", body, in_specs, out_specs), 0, 0
+        )
 
     def _build_merge(self):
         """Per-shard seed/fold-in: dedup routed host rows against each
@@ -961,9 +994,10 @@ class DistributedEngine:
                 rows, cnt, _lo = st[p]
                 arows, acnt = ad[p]
                 avalid = jnp.arange(arows.shape[0]) < acnt
-                nrows, ncnt, n_fresh, of = self._merge_block(
-                    rows, cnt, arows, avalid
-                )
+                with jax.named_scope("merge"):
+                    nrows, ncnt, n_fresh, of = self._merge_block(
+                        rows, cnt, arows, avalid
+                    )
                 total_new = total_new + n_fresh
                 overflow = overflow + of
                 out.extend([nrows[None], ncnt[None], cnt[None]])
@@ -983,7 +1017,7 @@ class DistributedEngine:
         out_specs.extend([P(), P()])
         return _Variant(
             self._shmap(
-                body, in_specs, out_specs,
+                "merge", body, in_specs, out_specs,
                 donate_argnums=self._state_donation(),
             ),
             0, 0,
@@ -998,11 +1032,15 @@ class DistributedEngine:
         nothing was committed).  Returns the raw outputs."""
         regrew = False
         for _ in range(self.max_regrows + 1):
-            rec = build_variant()
-            out = rec.fn(*flat)
-            total_new, dropped, overflow, joined = (
-                int(x) for x in out[-4:]
-            )
+            # the enqueue: variant lookup (trace and compile on a miss)
+            # and the call; then the block on the round's scalars
+            with span("dist.launch"):
+                rec = build_variant()
+                out = rec.fn(*flat)
+            with span("dist.wait"):
+                total_new, dropped, overflow, joined = (
+                    int(self._fetch(x)) for x in out[-4:]
+                )
             if overflow > 0:
                 raise RuntimeError(
                     f"relation buffer overflow: {overflow} rows past "
@@ -1026,11 +1064,10 @@ class DistributedEngine:
 
     def _mat_round(self, pairs):
         """One materialise/insert round over the live partitions."""
-        pkey = self._pair_key(pairs)
 
         def build():
             return self._variant(
-                ("mat", pkey, self._factor),
+                ("mat", self._pair_key(pairs), self._factor),
                 lambda: self._build_round(
                     pairs, acc_mode=False, union_acc=False,
                     use_restrict=False, factor=self._factor,
@@ -1038,10 +1075,16 @@ class DistributedEngine:
             )
 
         out, total_new, joined = self._run_round(build, self._flat_state())
-        for i, p in enumerate(self._preds):
-            self._state[p] = list(out[3 * i : 3 * i + 3])
-            self._counts[p] = int(np.asarray(out[3 * i + 1]).sum())
+        self._take_state(out)
         return total_new, joined
+
+    def _take_state(self, out) -> None:
+        """Adopt a program's per-predicate outputs as the state, and read
+        each predicate's row count back to the host."""
+        with span("dist.sync"):
+            for i, p in enumerate(self._preds):
+                self._state[p] = list(out[3 * i : 3 * i + 3])
+                self._counts[p] = int(self._fetch(out[3 * i + 1]).sum())
 
     def _acc_round(self, acc, pairs, *, union_acc, restrict):
         """One accumulator round (overdelete / rederive phases)."""
@@ -1104,9 +1147,10 @@ class DistributedEngine:
                     continue
                 pairs.append((rule, None))
             return self._resolve(pairs), skipped
-        delta_preds = {
-            p for p in self._preds if self._delta_count(p) > 0
-        }
+        with span("dist.sync"):
+            delta_preds = {
+                p for p in self._preds if self._delta_count(p) > 0
+            }
         for rule in stratum:
             for i, atom in enumerate(rule.body):
                 if atom.predicate not in delta_preds:
@@ -1142,36 +1186,38 @@ class DistributedEngine:
         r0 = len(self.stats.per_round)
         with span("dist.stratum", stratum=si, rules=len(stratum)):
             while rounds < max_rounds:
-                if not entry and self.seminaive:
-                    if not any(
-                        self._delta_count(p) > 0
-                        for p in body_preds
-                        if p in self._state
+                with span("dist.schedule"):
+                    if (
+                        not entry
+                        and self.seminaive
+                        and not self._any_delta(body_preds)
                     ):
                         break
-                pairs, skipped = self._schedule(stratum, entry, stable=stable)
+                    pairs, skipped = self._schedule(
+                        stratum, entry, stable=stable
+                    )
                 self.stats.rule_applications_skipped += skipped
                 if not pairs:
                     break
                 round_no = len(self.stats.per_round) + 1
-                rule_ids = sorted({
-                    self._rule_ids.get(rule, -1) for rule, _p, _pl in pairs
-                })
-                counts_before = (
-                    {
-                        p: np.asarray(self._state[p][1]).copy()
-                        for p in self._preds
-                    }
-                    if self._pjournal is not None
-                    else None
-                )
+                counts_before = None
+                if self._pjournal is not None:
+                    with span("dist.sync"):
+                        counts_before = {
+                            p: self._fetch(self._state[p][1]).copy()
+                            for p in self._preds
+                        }
                 with span(
                     "dist.round",
                     round=round_no,
                     stratum=si,
                     rule_applications=len(pairs),
-                    rule_ids=rule_ids,
                 ) as sp:
+                    if sp.recording:
+                        sp.set(rule_ids=sorted({
+                            self._rule_ids.get(rule, -1)
+                            for rule, _p, _pl in pairs
+                        }))
                     total_new, joined = self._mat_round(pairs)
                     sp.set(new_facts=total_new, rows_joined=joined)
                 if counts_before is not None:
@@ -1183,9 +1229,7 @@ class DistributedEngine:
                             pivot=-1 if pivot is None else pivot,
                         )
                     for p in self._preds:
-                        grow = (
-                            np.asarray(self._state[p][1]) - counts_before[p]
-                        )
+                        grow = self._fetch(self._state[p][1]) - counts_before[p]
                         for s in np.nonzero(grow)[0]:
                             self._record_dist(
                                 "apply", p, stratum=si, round_no=round_no,
@@ -1228,11 +1272,7 @@ class DistributedEngine:
                 pairs, _ = self._schedule(stratum, True, stable=stable)
                 pending = bool(pairs)
             else:
-                pending = any(
-                    self._delta_count(p) > 0
-                    for p in body_preds
-                    if p in self._state
-                )
+                pending = self._any_delta(body_preds)
         return rounds, not pending
 
     # -------------------------------------------------------------- #
@@ -1279,22 +1319,25 @@ class DistributedEngine:
 
     def materialise(self, dataset: dict[str, np.ndarray], max_rounds: int = 64):
         """Run rounds to fixpoint; returns per-predicate host arrays."""
-        self._prepare(dataset)
-        self.stats = DistributedStats()
-        from ..obs.provenance import get_journal
+        with span("dist.materialise", n_shards=self.n_shards) as sp:
+            with span("dist.prepare"):
+                self._prepare(dataset)
+            self.stats = DistributedStats()
+            from ..obs.provenance import get_journal
 
-        journal = get_journal()
-        self._pjournal = journal if journal.enabled else None
-        if self._pjournal is not None:
-            self._pjournal.attach_program(self.program)
-        strata = (
-            stratify(self.program) if self.seminaive else [list(self.program)]
-        )
-        self.stats.n_strata = len(strata)
-        rounds = 0
-        with span(
-            "dist.materialise", n_strata=len(strata), n_shards=self.n_shards
-        ):
+            journal = get_journal()
+            self._pjournal = journal if journal.enabled else None
+            if self._pjournal is not None:
+                self._pjournal.attach_program(self.program)
+            with span("dist.schedule"):
+                strata = (
+                    stratify(self.program)
+                    if self.seminaive
+                    else [list(self.program)]
+                )
+            self.stats.n_strata = len(strata)
+            sp.set(n_strata=len(strata))
+            rounds = 0
             for si, stratum in enumerate(strata):
                 used, converged = self._stratum_fixpoint(
                     si, stratum, max_rounds - rounds, naive_entry=True
@@ -1306,21 +1349,23 @@ class DistributedEngine:
                         f"max_rounds={max_rounds} (stratum {si} still has "
                         f"pending deltas) — increase max_rounds"
                     )
+            with span("dist.pull"):
+                result = {}
+                for p in self._preds:
+                    rows, cnt, _lo = self._state[p]
+                    buf = self._fetch(rows)
+                    c = self._fetch(cnt)
+                    flat_rows = np.concatenate(
+                        [buf[s, : c[s]] for s in range(self.n_shards)]
+                    )
+                    result[p] = unique_rows(flat_rows.astype(np.int64))
         self.rounds = rounds
         self.stats.rounds = rounds
         self.stats.plan_cache = self._plan_cache.counters()
+        # published after the pull, so that its reads are counted
         publish_distributed(self.stats)
         if self._pjournal is not None:
             self._pjournal.publish()
-        result = {}
-        for p in self._preds:
-            rows, cnt, _lo = self._state[p]
-            buf = np.asarray(rows)
-            c = np.asarray(cnt)
-            flat_rows = np.concatenate(
-                [buf[s, : c[s]] for s in range(self.n_shards)]
-            )
-            result[p] = unique_rows(flat_rows.astype(np.int64))
         return result
 
     # -------------------------------------------------------------- #
@@ -1342,15 +1387,16 @@ class DistributedEngine:
 
     def _pull_acc(self, acc: dict) -> dict[str, np.ndarray]:
         out = {}
-        for p in self._preds:
-            buf = np.asarray(acc[p][0])
-            cnt = np.asarray(acc[p][1])
-            if cnt.sum() == 0:
-                continue
-            rows = np.concatenate(
-                [buf[s, : cnt[s]] for s in range(self.n_shards)]
-            )
-            out[p] = unique_rows(rows.astype(np.int64))
+        with span("dist.pull"):
+            for p in self._preds:
+                buf = self._fetch(acc[p][0])
+                cnt = self._fetch(acc[p][1])
+                if cnt.sum() == 0:
+                    continue
+                rows = np.concatenate(
+                    [buf[s, : cnt[s]] for s in range(self.n_shards)]
+                )
+                out[p] = unique_rows(rows.astype(np.int64))
         return out
 
     def _route_pairs(self, rows_by_pred: dict) -> dict:
@@ -1507,11 +1553,12 @@ class DistributedEngine:
             flat = self._flat_state()
             for p in self._preds:
                 flat.extend(routed[p])
-            rec = self._variant(("delete", self._preds), self._build_delete)
-            out = rec.fn(*flat)
-            for i, p in enumerate(self._preds):
-                self._state[p] = list(out[3 * i : 3 * i + 3])
-                self._counts[p] = int(np.asarray(out[3 * i + 1]).sum())
+            with span("dist.launch"):
+                rec = self._variant(
+                    ("delete", self._preds), self._build_delete
+                )
+                out = rec.fn(*flat)
+            self._take_state(out)
 
         # --- rederive: explicit restores, one-step check, forward ------ #
         with span("dist.rederive") as sp:
@@ -1564,17 +1611,17 @@ class DistributedEngine:
         flat = self._flat_state()
         for p in self._preds:
             flat.extend(routed[p])
-        rec = self._variant(("merge", self._preds), self._build_merge)
-        out = rec.fn(*flat)
-        fresh, overflow = int(out[-2]), int(out[-1])
+        with span("dist.launch"):
+            rec = self._variant(("merge", self._preds), self._build_merge)
+            out = rec.fn(*flat)
+        with span("dist.wait"):
+            fresh, overflow = int(self._fetch(out[-2])), int(self._fetch(out[-1]))
         if overflow > 0:
             raise RuntimeError(
                 f"relation buffer overflow: {overflow} rows past capacity "
                 f"{self.capacity} — increase capacity"
             )
-        for i, p in enumerate(self._preds):
-            self._state[p] = list(out[3 * i : 3 * i + 3])
-            self._counts[p] = int(np.asarray(out[3 * i + 1]).sum())
+        self._take_state(out)
         if count_inserted:
             st.n_inserted += fresh
         return fresh
@@ -1586,9 +1633,11 @@ class DistributedEngine:
         earlier strata propagate without host-side seed bookkeeping."""
         with span("dist.insert") as sp:
             # a host copy: the merge below donates the device counts
-            sweep_lo = {
-                p: np.asarray(self._state[p][1]).copy() for p in self._preds
-            }
+            with span("dist.sync"):
+                sweep_lo = {
+                    p: self._fetch(self._state[p][1]).copy()
+                    for p in self._preds
+                }
             self._merge_host_rows(adds, st, count_inserted=True)
             strata = (
                 stratify(self.program)
@@ -1619,16 +1668,17 @@ class DistributedEngine:
         """Flat per-predicate materialisation (sorted unique int64 rows,
         empty predicates omitted — the IncrementalStore contract)."""
         out = {}
-        for p in self._preds:
-            rows, cnt, _lo = self._state[p]
-            buf = np.asarray(rows)
-            c = np.asarray(cnt)
-            if c.sum() == 0:
-                continue
-            flat_rows = np.concatenate(
-                [buf[s, : c[s]] for s in range(self.n_shards)]
-            )
-            out[p] = unique_rows(flat_rows.astype(np.int64))
+        with span("dist.pull"):
+            for p in self._preds:
+                rows, cnt, _lo = self._state[p]
+                buf = self._fetch(rows)
+                c = self._fetch(cnt)
+                if c.sum() == 0:
+                    continue
+                flat_rows = np.concatenate(
+                    [buf[s, : c[s]] for s in range(self.n_shards)]
+                )
+                out[p] = unique_rows(flat_rows.astype(np.int64))
         return out
 
     def check_integrity(self, host) -> None:

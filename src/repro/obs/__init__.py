@@ -4,7 +4,8 @@ The one telemetry layer every subsystem reports through
 (DESIGN.md §Observability):
 
 * :func:`span` / :func:`instant` — nested host-side tracing spans
-  (``perf_counter_ns``; free when disabled).  Emitted for fixpoint
+  (``perf_counter_ns``; free when disabled), and TraceMe events in the
+  ``jax.profiler`` trace whenever a profiler session is active.  Emitted for fixpoint
   rounds, strata, (rule, pivot) applications, exchange rounds, DRed
   phases, WAL appends, checkpoints/restores, compaction epochs, and
   served queries/apply batches.

@@ -30,6 +30,7 @@ __all__ = [
     "MATERIALISATION_GAUGES",
     "INCREMENTAL_COUNTERS",
     "DISTRIBUTED_COUNTERS",
+    "DISTRIBUTED_GAUGES",
     "SERVING_GAUGES",
 ]
 
@@ -94,8 +95,14 @@ INCREMENTAL_COUNTERS = (
     "time_insert",
 )
 
-#: DistributedStats extras beyond the materialisation base
+#: DistributedStats fields the engine sets that accumulate: the
+#: materialisation base fields it fills, and the exchange, maintenance
+#: and device->host read counters the host engines have no analogue for
 DISTRIBUTED_COUNTERS = (
+    "rounds",
+    "n_rule_applications",
+    "rule_applications_skipped",
+    "time_total",
     "rows_joined",
     "exchanges",
     "exchanges_skipped",
@@ -106,7 +113,12 @@ DISTRIBUTED_COUNTERS = (
     "n_rederived",
     "n_deleted",
     "n_inserted",
+    "host_syncs",
 )
+
+#: DistributedStats levels the engine sets (``n_facts``/``n_meta_facts``
+#: and the per-phase times of the host engines are never set there)
+DISTRIBUTED_GAUGES = ("n_strata", "epoch")
 
 
 def _publish_rule_scope(reg: MetricsRegistry, stats) -> None:
@@ -171,13 +183,10 @@ def publish_distributed(
     """Publish a :class:`~repro.core.distributed.DistributedStats`
     (after ``materialise`` and after every ``apply``)."""
     reg = registry if registry is not None else get_registry()
-    for f in MATERIALISATION_COUNTERS:
-        reg.counter(f"{prefix}.{f}").inc(getattr(stats, f))
-    for f in MATERIALISATION_GAUGES:
-        reg.gauge(f"{prefix}.{f}").set(getattr(stats, f))
     for f in DISTRIBUTED_COUNTERS:
         reg.counter(f"{prefix}.{f}").inc(getattr(stats, f))
-    reg.gauge(f"{prefix}.epoch").set(stats.epoch)
+    for f in DISTRIBUTED_GAUGES:
+        reg.gauge(f"{prefix}.{f}").set(getattr(stats, f))
     _publish_rule_scope(reg, stats)
     _publish_plan_cache(reg, prefix, stats.plan_cache)
 

@@ -1,9 +1,13 @@
-"""Span tracer: nested, low-overhead, host-side only.
+"""Span tracer: nested, low-overhead, host-side only, with two sinks.
 
 One process-wide :class:`Tracer` (swap it with :func:`set_tracer`)
 records *complete* spans — name, start, duration, nesting depth, and a
-flat attribute dict — with ``time.perf_counter_ns`` timestamps.  Spans
-are context managers::
+flat attribute dict — with ``time.perf_counter_ns`` timestamps.  The
+same spans also go to the ``jax.profiler`` trace as TraceMe events
+whenever a profiler session is active (``jax.profiler.start_trace``),
+whether or not the tracer is enabled: they then sit in the session's
+``.xplane.pb`` on the device trace's clock, with their attributes as
+event stats.  Spans are context managers::
 
     from repro.obs import span
 
@@ -14,16 +18,25 @@ Design constraints (DESIGN.md §Observability):
 
 * **Disabled is free.**  The default tracer is disabled;
   ``tracer.span(...)`` then returns a shared no-op singleton — no event
-  allocation, no timestamp read, no stack push.  Engines can leave
-  their instrumentation unguarded in host-side loops.
+  allocation, no timestamp read, no stack push — unless a profiler
+  session is active (one ``TraceMe.is_enabled()`` check decides).
+  Engines can leave their instrumentation unguarded in host-side loops;
+  attributes that cost something to build are attached with
+  ``sp.set(...)`` under ``if sp.recording``.
 * **Host boundaries only.**  Spans read the wall clock and append to a
-  Python list; they must never execute inside traced/jitted code, where
-  the side effect would fire once per trace instead of per execution
-  (the same rule the kernel meter and ``DistributedStats`` follow).
+  Python list (or emit a TraceMe); they must never execute inside
+  traced/jitted code, where the side effect would fire once per trace
+  instead of per execution (the same rule the kernel meter and
+  ``DistributedStats`` follow).
   Instrument where the engines already count rounds.
 * **Bounded.**  At ``max_events`` the tracer stops recording (and
   counts the drops) instead of growing without bound under a serving
   loop left tracing for hours.
+
+While a profiler session is active, a ``gc.callbacks`` hook also
+writes every garbage collection as a ``host.gc`` span (its generation
+and the objects collected as stats), so that host stalls show on the
+same clock.
 
 The recorded span list converts losslessly to the Chrome trace-event /
 Perfetto JSON format (:mod:`repro.obs.export`) — open the file in
@@ -32,8 +45,14 @@ Perfetto JSON format (:mod:`repro.obs.export`) — open the file in
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+#: True while a ``jax.profiler`` session records (a C++ flag read)
+profiling = TraceAnnotation.is_enabled
 
 __all__ = [
     "Tracer",
@@ -42,6 +61,7 @@ __all__ = [
     "set_tracer",
     "span",
     "instant",
+    "profiling",
 ]
 
 
@@ -70,6 +90,8 @@ class _NoopSpan:
     """Shared do-nothing context manager for the disabled fast path."""
 
     __slots__ = ()
+    #: no sink records: callers skip building attributes
+    recording = False
 
     def __enter__(self):
         return self
@@ -85,6 +107,17 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+class _ProfilerSpan(TraceAnnotation):
+    """A span for the profiler sink alone: a TraceMe whose ``set``
+    attaches event stats."""
+
+    recording = True
+
+    def set(self, **kw):
+        self.set_metadata(**kw)
+        return self
+
+
 class _Span:
     """Live span handle; records itself into the tracer on ``__exit__``.
 
@@ -94,27 +127,35 @@ class _Span:
     exporter does not care.
     """
 
-    __slots__ = ("_tracer", "name", "args", "_start", "_depth")
+    __slots__ = ("_tracer", "name", "args", "_start", "_depth", "_prof")
+    recording = True
 
     def __init__(self, tracer: Tracer, name: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._prof = _ProfilerSpan(name, **args) if profiling() else None
 
     def set(self, **kw):
         """Attach attributes discovered mid-span (e.g. cache hit/miss)."""
         self.args.update(kw)
+        if self._prof is not None:
+            self._prof.set(**kw)
         return self
 
     def __enter__(self):
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        if self._prof is not None:
+            self._prof.__enter__()
         self._start = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self._start
+        if self._prof is not None:
+            self._prof.__exit__(*exc)
         tracer = self._tracer
         stack = tracer._stack()
         if stack and stack[-1] is self:
@@ -196,15 +237,20 @@ class Tracer:
     # ------------------------------------------------------------------ #
     def span(self, name: str, **args):
         """Context manager timing one named span.  Disabled tracers
-        return a shared no-op singleton (the zero-cost fast path)."""
+        return a shared no-op singleton (the zero-cost fast path), or a
+        bare TraceMe while a profiler session is active."""
         if not self.enabled:
-            return _NOOP
+            return _ProfilerSpan(name, **args) if profiling() else _NOOP
         return _Span(self, name, args)
 
     def instant(self, name: str, **args) -> None:
         """Zero-duration marker event (regrows, WAL appends, ...).
         Recorded with ``dur_ns == -1`` so the exporter can tell a marker
-        from a genuinely sub-resolution span."""
+        from a genuinely sub-resolution span; a zero-length TraceMe in
+        an active profiler session."""
+        if profiling():
+            with TraceAnnotation(name, **args):
+                pass
         if not self.enabled:
             return
         self._record(
@@ -264,3 +310,28 @@ def span(name: str, **args):
 def instant(name: str, **args) -> None:
     """Instant event on the process-wide tracer."""
     _TRACER.instant(name, **args)
+
+
+class _GcSpans:
+    """``gc.callbacks`` hook: one ``host.gc`` TraceMe per collection,
+    opened at ``"start"`` and closed at ``"stop"``, while a profiler
+    session is active (one flag read per collection otherwise)."""
+
+    def __init__(self):
+        self.open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if profiling():
+                self.open = _ProfilerSpan(
+                    "host.gc", generation=info["generation"]
+                )
+                self.open.__enter__()
+        elif self.open is not None:
+            sp, self.open = self.open, None
+            sp.set(collected=info["collected"])
+            sp.__exit__(None, None, None)
+
+
+if not any(isinstance(cb, _GcSpans) for cb in gc.callbacks):
+    gc.callbacks.append(_GcSpans())

@@ -56,3 +56,33 @@ def leak_check():
         f"{cap0}B at entry -> {cap1}B at exit"
     )
     reg.reset("mem.")
+
+
+@pytest.fixture
+def profile_host_events(tmp_path):
+    """``run(fn)`` calls ``fn()`` inside a ``jax.profiler`` session and
+    returns the host events of its ``.xplane.pb`` as ``(name, start_ns,
+    end_ns, stats)``, in start order."""
+    import glob
+
+    import jax
+
+    def run(fn):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        out = []
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    out.extend(
+                        (e.name, int(e.start_ns),
+                         int(e.start_ns + e.duration_ns), dict(e.stats))
+                        for e in line.events
+                    )
+        return sorted(out, key=lambda ev: (ev[1], -ev[2]))
+
+    return run

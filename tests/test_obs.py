@@ -17,6 +17,7 @@ from repro.obs import (
     get_registry,
     get_tracer,
     instant,
+    publish_distributed,
     publish_materialisation,
     set_registry,
     set_tracer,
@@ -25,9 +26,12 @@ from repro.obs import (
     write_metrics,
 )
 from repro.obs.adapters import (
+    DISTRIBUTED_COUNTERS,
+    DISTRIBUTED_GAUGES,
     MATERIALISATION_COUNTERS,
     MATERIALISATION_GAUGES,
 )
+from repro.obs.trace import profiling
 
 
 @pytest.fixture
@@ -249,6 +253,68 @@ class TestChromeTrace:
 
 
 # --------------------------------------------------------------------- #
+# the profiler sink: spans as TraceMe events in a jax.profiler session
+# --------------------------------------------------------------------- #
+class TestProfilerSink:
+    def test_spans_reach_the_profiler_with_the_tracer_disabled(
+        self, profile_host_events
+    ):
+        prev = set_tracer(Tracer(enabled=False))
+        try:
+            def work():
+                assert profiling()
+                with span("x.outer", k=1) as sp:
+                    assert sp.recording
+                    sp.set(n=2)
+                    with span("x.inner"):
+                        pass
+                instant("x.mark", factor=3)
+
+            events = profile_host_events(work)
+            assert get_tracer().events == []  # the tracer stayed off
+        finally:
+            set_tracer(prev)
+        mine = {name: (s, e, st) for name, s, e, st in events
+                if name.startswith("x.")}
+        assert set(mine) == {"x.outer", "x.inner", "x.mark"}
+        (os_, oe, ost), (is_, ie, _) = mine["x.outer"], mine["x.inner"]
+        assert ost == {"k": 1, "n": 2}
+        assert os_ <= is_ and ie <= oe  # nested on the profiler's clock
+        ms, me, mst = mine["x.mark"]
+        assert mst == {"factor": 3} and me - ms < 1_000_000
+
+    def test_both_sinks_record_one_span(self, tracer, profile_host_events):
+        def work():
+            with span("x.both", k=1) as sp:
+                sp.set(hit=True)
+
+        events = profile_host_events(work)
+        (rec,) = tracer.events
+        assert rec.name == "x.both" and rec.args == {"k": 1, "hit": True}
+        assert [st for name, _s, _e, st in events if name == "x.both"] == [
+            {"k": 1, "hit": 1}
+        ]
+
+    def test_no_session_no_tracer_is_the_shared_noop(self):
+        prev = set_tracer(Tracer(enabled=False))
+        try:
+            assert not profiling()
+            s1, s2 = span("a"), span("b", k=1)
+            assert s1 is s2 and not s1.recording
+        finally:
+            set_tracer(prev)
+
+    def test_gc_spans_only_in_a_session(self, profile_host_events):
+        import gc
+
+        gc.collect()  # no session: the hook opens nothing
+        events = profile_host_events(gc.collect)
+        gcs = [st for name, _s, _e, st in events if name == "host.gc"]
+        assert gcs and gcs[-1]["generation"] == 2
+        assert "collected" in gcs[-1]
+
+
+# --------------------------------------------------------------------- #
 # adapters: registry parity with the legacy stats dataclasses
 # --------------------------------------------------------------------- #
 class TestAdapterParity:
@@ -272,6 +338,28 @@ class TestAdapterParity:
         snap = registry.snapshot("cmat.")
         assert snap["cmat.rounds"] == 2 * stats.rounds
         assert snap["cmat.n_facts"] == stats.n_facts  # gauge: last write
+
+
+    def test_dist_snapshot_holds_what_the_engine_sets(self, registry):
+        import jax
+        from jax.sharding import Mesh
+
+        from repro.core.distributed import DistributedEngine
+
+        program, dataset, _ = paper_example()
+        eng = DistributedEngine(
+            DistributedEngine.supported_program(program),
+            Mesh(np.asarray(jax.devices()[:1]), ("data",)), capacity=1 << 8,
+        )
+        eng.materialise(dataset)
+        snap = registry.snapshot("dist.")
+        for f in DISTRIBUTED_COUNTERS + DISTRIBUTED_GAUGES:
+            assert snap[f"dist.{f}"] == pytest.approx(getattr(eng.stats, f)), f
+        assert snap["dist.host_syncs"] > 0
+        # fields of the host engines that the sharded engine never sets
+        for f in ("time_compress", "time_match", "time_join", "time_dedup",
+                  "old_snapshot_scans", "n_facts", "n_meta_facts"):
+            assert f"dist.{f}" not in snap, f
 
 
 # --------------------------------------------------------------------- #
@@ -322,6 +410,37 @@ class TestTracingIsInert:
         assert (
             stats_on.n_rule_applications == stats_off.n_rule_applications
         )
+
+
+    def test_distributed_identical_under_the_profiler(
+        self, registry, profile_host_events
+    ):
+        import jax
+        from jax.sharding import Mesh
+
+        from repro.core.distributed import DistributedEngine
+
+        program, dataset, _ = lubm_like(
+            n_dept=2, n_students=15, n_courses=3, seed=1
+        )
+        eng = DistributedEngine(
+            DistributedEngine.supported_program(program),
+            Mesh(np.asarray(jax.devices()[:1]), ("data",)), capacity=1 << 11,
+        )
+        off = eng.materialise(dataset)
+        stats_off = eng.stats
+        got = {}
+        events = profile_host_events(
+            lambda: got.update(on=eng.materialise(dataset))
+        )
+        assert any(name == "dist.round" for name, *_ in events)
+        on, stats_on = got["on"], eng.stats
+        assert sorted(on) == sorted(off)
+        for pred in on:
+            np.testing.assert_array_equal(on[pred], off[pred])
+        for f in ("rounds", "n_rule_applications", "rows_joined",
+                  "rule_applications_skipped", "host_syncs"):
+            assert getattr(stats_on, f) == getattr(stats_off, f), f
 
 
 # --------------------------------------------------------------------- #
