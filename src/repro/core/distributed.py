@@ -91,9 +91,24 @@ class DistributedStats(MaterialisationStats):
     n_rederived: int = 0
     n_deleted: int = 0
     n_inserted: int = 0
-    #: device->host reads (counts, delta bounds, round scalars, result
-    #: buffers), each counted once by ``DistributedEngine._fetch``
+    #: device->host waits: each call of ``DistributedEngine._fetch``
+    #: counts one, however many arrays it reads (a program's packed
+    #: block, or every result buffer of a pull)
     host_syncs: int = 0
+
+
+#: the psum'd scalars at the head of a round program's packed block
+ROUND_SCALARS = ("total_new", "dropped", "overflow", "joined")
+#: ... and of a merge program's
+MERGE_SCALARS = ("total_new", "overflow")
+
+
+def _packed_block(parts) -> jax.Array:
+    """A program's packed block, one ``(1, K)`` int32 row a shard: its
+    psum'd scalars, then each predicate's new count on the shard, in
+    ``_preds`` order.  ``parts`` are ``(1,)`` arrays.  The host reads
+    nothing else of a program, and reads it once."""
+    return jnp.concatenate(parts)[None]
 
 
 def _hash_shard(keys: jax.Array, n_shards: int) -> jax.Array:
@@ -319,8 +334,12 @@ class DistributedEngine:
         self._variants: dict = {}
         #: per-predicate sharded state: pred -> [rows, count, delta_lo]
         self._state: dict[str, list] | None = None
+        #: host mirror of the state's per-shard counts and watermarks:
+        #: pred -> (cnt, lo), int32 arrays of shape (n_shards,)
+        self._mirror: dict[str, tuple[np.ndarray, np.ndarray]] = {}
         self._preds: tuple[str, ...] = ()
         self._arities: dict[str, int] = {}
+        #: global row count per predicate (the mirror's cnt summed)
         self._counts: dict[str, int] = {}
         #: host-side explicit fact set (int64 rows; the apply() contract)
         self.explicit: dict[str, np.ndarray] = {}
@@ -439,23 +458,29 @@ class DistributedEngine:
             out.extend(self._state[p])
         return out
 
-    def _fetch(self, x) -> np.ndarray:
-        """Read one device array to the host.  Every device->host read
-        of the engine goes through here, and is counted in
-        ``stats.host_syncs``."""
+    def _fetch(self, x):
+        """Read a device array to the host, or a sequence of them as a
+        list (every copy starts before the first is waited on).  Every
+        device->host read of the engine goes through here; each call is
+        one host wait, counted in ``stats.host_syncs``."""
         self.stats.host_syncs += 1
+        if isinstance(x, (list, tuple)):
+            for a in x:
+                a.copy_to_host_async()
+            return [np.asarray(a) for a in x]
         return np.asarray(x)
 
+    def _set_mirror(self, pred: str, cnt: np.ndarray, lo: np.ndarray) -> None:
+        self._mirror[pred] = (cnt, lo)
+        self._counts[pred] = int(cnt.sum())
+
     def _delta_count(self, pred: str) -> int:
-        _, cnt, lo = self._state[pred]
-        return int((self._fetch(cnt) - self._fetch(lo)).sum())
+        cnt, lo = self._mirror[pred]
+        return int((cnt - lo).sum())
 
     def _any_delta(self, preds) -> bool:
         """True when some predicate of ``preds`` holds a delta."""
-        with span("dist.sync"):
-            return any(
-                self._delta_count(p) > 0 for p in preds if p in self._state
-            )
+        return any(self._delta_count(p) > 0 for p in preds if p in self._mirror)
 
     # -------------------------------------------------------------- #
     # planning
@@ -863,6 +888,7 @@ class DistributedEngine:
                     joined = joined + j
 
             new_flat = []
+            new_counts = []
             total_new = jnp.zeros((), jnp.int32)
             overflow = jnp.zeros((), jnp.int32)
             for pred in preds:
@@ -874,6 +900,7 @@ class DistributedEngine:
                 if not blocks:
                     # no derivations: the delta still gets consumed
                     new_flat.extend([trows[None], tcnt[None], tcnt[None]])
+                    new_counts.append(tcnt[None])
                     continue
                 rows = jnp.concatenate([b[0] for b in blocks])
                 valid = jnp.concatenate([b[1] for b in blocks])
@@ -894,13 +921,12 @@ class DistributedEngine:
                 total_new = total_new + n_fresh
                 overflow = overflow + of
                 new_flat.extend([nrows[None], ncnt[None], tcnt[None]])
+                new_counts.append(ncnt[None])
 
-            return tuple(new_flat) + (
-                jax.lax.psum(total_new, axis),
-                jax.lax.psum(dropped, axis),
-                jax.lax.psum(overflow, axis),
-                jax.lax.psum(joined, axis),
-            )
+            scalars = (total_new, dropped, overflow, joined)
+            return tuple(new_flat) + (_packed_block(
+                [jax.lax.psum(x, axis)[None] for x in scalars] + new_counts
+            ),)
 
         in_specs: list = []
         if acc_mode:
@@ -917,7 +943,7 @@ class DistributedEngine:
         out_specs: list = []
         for _ in preds:
             out_specs.extend(self._spec3())
-        out_specs.extend([P(), P(), P(), P()])
+        out_specs.append(P(self.axis))
         n_ex, n_sk = self._static_exchange_counts(pairs)
         kind = "acc_round" if acc_mode else "round"
         return _Variant(
@@ -958,7 +984,7 @@ class DistributedEngine:
                 )
                 nrows = jnp.where((idx < n_keep)[:, None], rows[perm], EMPTY)
                 out.extend([nrows[None], n_keep[None], n_keep[None]])
-            return tuple(out)
+            return tuple(out) + (_packed_block(out[1::3]),)
 
         in_specs: list = []
         for _ in preds:
@@ -968,6 +994,7 @@ class DistributedEngine:
         out_specs: list = []
         for _ in preds:
             out_specs.extend(self._spec3())
+        out_specs.append(P(self.axis))
         return _Variant(
             self._shmap("delete", body, in_specs, out_specs), 0, 0
         )
@@ -1001,10 +1028,10 @@ class DistributedEngine:
                 total_new = total_new + n_fresh
                 overflow = overflow + of
                 out.extend([nrows[None], ncnt[None], cnt[None]])
-            return tuple(out) + (
-                jax.lax.psum(total_new, axis),
-                jax.lax.psum(overflow, axis),
-            )
+            return tuple(out) + (_packed_block(
+                [jax.lax.psum(x, axis)[None] for x in (total_new, overflow)]
+                + out[1::3]
+            ),)
 
         in_specs: list = []
         for _ in preds:
@@ -1014,7 +1041,7 @@ class DistributedEngine:
         out_specs: list = []
         for _ in preds:
             out_specs.extend(self._spec3())
-        out_specs.extend([P(), P()])
+        out_specs.append(P(self.axis))
         return _Variant(
             self._shmap(
                 "merge", body, in_specs, out_specs,
@@ -1029,18 +1056,22 @@ class DistributedEngine:
     def _run_round(self, build_variant, flat):
         """Run one jitted round; on exchange/join overflow, double the
         padding factor and retry the *same* inputs (rounds are pure, so
-        nothing was committed).  Returns the raw outputs."""
+        nothing was committed).  Returns the outputs, the packed block
+        last and read to the host, with the round's new facts and joined
+        rows."""
         regrew = False
+        n = len(ROUND_SCALARS)
         for _ in range(self.max_regrows + 1):
             # the enqueue: variant lookup (trace and compile on a miss)
-            # and the call; then the block on the round's scalars
+            # and the call; then the block on the round's one read
             with span("dist.launch"):
                 rec = build_variant()
                 out = rec.fn(*flat)
             with span("dist.wait"):
-                total_new, dropped, overflow, joined = (
-                    int(self._fetch(x)) for x in out[-4:]
-                )
+                block = self._fetch(out[-1])
+            total_new, dropped, overflow, joined = (
+                int(x) for x in block[0, :n]
+            )
             if overflow > 0:
                 raise RuntimeError(
                     f"relation buffer overflow: {overflow} rows past "
@@ -1052,7 +1083,7 @@ class DistributedEngine:
                 self.stats.exchanges += rec.n_exchanges
                 self.stats.exchanges_skipped += rec.n_exchanges_skipped
                 self.stats.rows_joined += joined
-                return out, total_new, joined
+                return (*out[:-1], block[:, n:]), total_new, joined
             self._factor *= 2
             regrew = True
             self.stats.exchange_regrows += 1
@@ -1078,13 +1109,17 @@ class DistributedEngine:
         self._take_state(out)
         return total_new, joined
 
-    def _take_state(self, out) -> None:
-        """Adopt a program's per-predicate outputs as the state, and read
-        each predicate's row count back to the host."""
-        with span("dist.sync"):
-            for i, p in enumerate(self._preds):
-                self._state[p] = list(out[3 * i : 3 * i + 3])
-                self._counts[p] = int(self._fetch(out[3 * i + 1]).sum())
+    def _take_state(self, out, *, consumed: bool = False) -> None:
+        """Adopt a program's per-predicate outputs as the state, and the
+        new counts that ``out`` ends with (its packed block, already on
+        the host, less the scalars) as the mirror's: each watermark
+        becomes the previous count — the program appended its new rows
+        as the delta — or, ``consumed``, the new count (no delta left)."""
+        counts = out[-1]
+        for i, p in enumerate(self._preds):
+            self._state[p] = list(out[3 * i : 3 * i + 3])
+            cnt = counts[:, i].copy()
+            self._set_mirror(p, cnt, cnt if consumed else self._mirror[p][0])
 
     def _acc_round(self, acc, pairs, *, union_acc, restrict):
         """One accumulator round (overdelete / rederive phases)."""
@@ -1147,10 +1182,7 @@ class DistributedEngine:
                     continue
                 pairs.append((rule, None))
             return self._resolve(pairs), skipped
-        with span("dist.sync"):
-            delta_preds = {
-                p for p in self._preds if self._delta_count(p) > 0
-            }
+        delta_preds = {p for p in self._preds if self._delta_count(p) > 0}
         for rule in stratum:
             for i, atom in enumerate(rule.body):
                 if atom.predicate not in delta_preds:
@@ -1181,6 +1213,7 @@ class DistributedEngine:
         if sweep_lo is not None:
             for p in self._preds:
                 self._state[p][2] = self._to_shards(sweep_lo[p])
+                self._set_mirror(p, self._mirror[p][0], sweep_lo[p])
         entry = naive_entry
         rounds = 0
         r0 = len(self.stats.per_round)
@@ -1202,11 +1235,7 @@ class DistributedEngine:
                 round_no = len(self.stats.per_round) + 1
                 counts_before = None
                 if self._pjournal is not None:
-                    with span("dist.sync"):
-                        counts_before = {
-                            p: self._fetch(self._state[p][1]).copy()
-                            for p in self._preds
-                        }
+                    counts_before = {p: self._mirror[p][0] for p in self._preds}
                 with span(
                     "dist.round",
                     round=round_no,
@@ -1229,7 +1258,7 @@ class DistributedEngine:
                             pivot=-1 if pivot is None else pivot,
                         )
                     for p in self._preds:
-                        grow = self._fetch(self._state[p][1]) - counts_before[p]
+                        grow = self._mirror[p][0] - counts_before[p]
                         for s in np.nonzero(grow)[0]:
                             self._record_dist(
                                 "apply", p, stratum=si, round_no=round_no,
@@ -1303,7 +1332,6 @@ class DistributedEngine:
             full[p] = unique_rows(rows) if rows.shape[0] else rows
         self._preds = preds
         self._arities = arities
-        self._counts = {p: int(full[p].shape[0]) for p in preds}
         self.explicit = {
             p: rows for p, rows in full.items() if rows.shape[0]
         }
@@ -1313,9 +1341,13 @@ class DistributedEngine:
             {p: rows.astype(np.int32) for p, rows in full.items()}
         )
         self._state = {}
+        self._mirror = {}
+        self._counts = {}
         for p in preds:
             rows, cnt = self._sharded_pair(routed, p)
             self._state[p] = [rows, cnt, jnp.zeros_like(cnt)]
+            host_cnt = routed[p][1]
+            self._set_mirror(p, host_cnt, np.zeros_like(host_cnt))
 
     def materialise(self, dataset: dict[str, np.ndarray], max_rounds: int = 64):
         """Run rounds to fixpoint; returns per-predicate host arrays."""
@@ -1350,15 +1382,11 @@ class DistributedEngine:
                         f"pending deltas) — increase max_rounds"
                     )
             with span("dist.pull"):
-                result = {}
-                for p in self._preds:
-                    rows, cnt, _lo = self._state[p]
-                    buf = self._fetch(rows)
-                    c = self._fetch(cnt)
-                    flat_rows = np.concatenate(
-                        [buf[s, : c[s]] for s in range(self.n_shards)]
-                    )
-                    result[p] = unique_rows(flat_rows.astype(np.int64))
+                pulled = self._pull()
+                result = {
+                    p: pulled.get(p, np.zeros((0, self._arities[p]), np.int64))
+                    for p in self._preds
+                }
         self.rounds = rounds
         self.stats.rounds = rounds
         self.stats.plan_cache = self._plan_cache.counters()
@@ -1558,7 +1586,9 @@ class DistributedEngine:
                     ("delete", self._preds), self._build_delete
                 )
                 out = rec.fn(*flat)
-            self._take_state(out)
+            with span("dist.sync"):
+                block = self._fetch(out[-1])
+            self._take_state((*out[:-1], block), consumed=True)
 
         # --- rederive: explicit restores, one-step check, forward ------ #
         with span("dist.rederive") as sp:
@@ -1615,13 +1645,15 @@ class DistributedEngine:
             rec = self._variant(("merge", self._preds), self._build_merge)
             out = rec.fn(*flat)
         with span("dist.wait"):
-            fresh, overflow = int(self._fetch(out[-2])), int(self._fetch(out[-1]))
+            block = self._fetch(out[-1])
+        n = len(MERGE_SCALARS)
+        fresh, overflow = (int(x) for x in block[0, :n])
         if overflow > 0:
             raise RuntimeError(
                 f"relation buffer overflow: {overflow} rows past capacity "
                 f"{self.capacity} — increase capacity"
             )
-        self._take_state(out)
+        self._take_state((*out[:-1], block[:, n:]))
         if count_inserted:
             st.n_inserted += fresh
         return fresh
@@ -1632,12 +1664,7 @@ class DistributedEngine:
         as its delta (the ``sweep_lo`` watermark), so derived facts of
         earlier strata propagate without host-side seed bookkeeping."""
         with span("dist.insert") as sp:
-            # a host copy: the merge below donates the device counts
-            with span("dist.sync"):
-                sweep_lo = {
-                    p: self._fetch(self._state[p][1]).copy()
-                    for p in self._preds
-                }
+            sweep_lo = {p: self._mirror[p][0].copy() for p in self._preds}
             self._merge_host_rows(adds, st, count_inserted=True)
             strata = (
                 stratify(self.program)
@@ -1667,18 +1694,24 @@ class DistributedEngine:
     def to_dict(self) -> dict[str, np.ndarray]:
         """Flat per-predicate materialisation (sorted unique int64 rows,
         empty predicates omitted — the IncrementalStore contract)."""
-        out = {}
         with span("dist.pull"):
-            for p in self._preds:
-                rows, cnt, _lo = self._state[p]
-                buf = self._fetch(rows)
-                c = self._fetch(cnt)
-                if c.sum() == 0:
-                    continue
-                flat_rows = np.concatenate(
-                    [buf[s, : c[s]] for s in range(self.n_shards)]
-                )
-                out[p] = unique_rows(flat_rows.astype(np.int64))
+            return self._pull()
+
+    def _pull(self) -> dict[str, np.ndarray]:
+        """The state's facts on the host (sorted unique int64 rows) of
+        every predicate that has any: counts from the mirror, and every
+        such buffer read in one wait."""
+        live = [p for p in self._preds if self._counts[p]]
+        if not live:
+            return {}
+        bufs = self._fetch([self._state[p][0] for p in live])
+        out = {}
+        for p, buf in zip(live, bufs):
+            cnt = self._mirror[p][0]
+            flat_rows = np.concatenate(
+                [buf[s, : cnt[s]] for s in range(self.n_shards)]
+            )
+            out[p] = unique_rows(flat_rows.astype(np.int64))
         return out
 
     def check_integrity(self, host) -> None:

@@ -21,6 +21,17 @@ from repro.core.generators import chain, lubm_like, paper_example
 
 mesh = Mesh(np.asarray(jax.devices()).reshape(4), ("data",))
 
+
+def mirror_exact(eng):
+    # the host mirror of counts and watermarks equals the device's
+    for p in eng._preds:
+        cnt, lo = eng._mirror[p]
+        assert cnt.shape == (4,) and cnt.dtype == np.int32, p
+        assert (cnt == np.asarray(eng._state[p][1])).all(), p
+        assert (lo == np.asarray(eng._state[p][2])).all(), p
+        assert eng._counts[p] == int(cnt.sum()), p
+
+
 engines = {}
 datasets = {}
 for name, gen in [
@@ -38,6 +49,11 @@ for name, gen in [
     got = {p: {tuple(map(int, r)) for r in rows}
            for p, rows in got.items() if rows.shape[0]}
     assert got == want, f"{name}: mismatch"
+    mirror_exact(eng)
+    # one wait a round, on its packed block, and one for the pull
+    assert eng.stats.host_syncs == (
+        eng.stats.rounds + eng.stats.exchange_regrows + 1
+    ), name
     engines[name], datasets[name] = eng, dataset
     print(f"{name} OK rounds={eng.rounds} "
           f"skipped={eng.stats.rule_applications_skipped} "
@@ -60,8 +76,10 @@ kept = {"edge": np.asarray(
     [r for r in dataset["edge"].tolist()
      if tuple(r) not in {tuple(x) for x in dels["edge"].tolist()}],
     np.int64)}
+mirror_exact(eng)
 eng.check_integrity(flat_seminaive(program, kept))
 eng.apply(additions=dels)
+mirror_exact(eng)
 eng.check_integrity(flat_seminaive(program, dataset))
 print("APPLY OK")
 print("MULTISHARD OK")
