@@ -95,6 +95,9 @@ class DistributedStats(MaterialisationStats):
     #: counts one, however many arrays it reads (a program's packed
     #: block, or every result buffer of a pull)
     host_syncs: int = 0
+    #: int32 words of a binary fact's key in the programs that ran
+    #: (:func:`key_words` of the largest id the engine has seen)
+    key_words: int = 1
 
 
 #: the psum'd scalars at the head of a round program's packed block
@@ -127,68 +130,102 @@ def _hash_shard_np(keys: np.ndarray, n_shards: int) -> np.ndarray:
 # jnp primitives (device twins of core.util; kernels/ accelerates these)
 # --------------------------------------------------------------------- #
 def sorted_member_jnp(
-    a: jax.Array, b_sorted: jax.Array, a_sorted: bool = False
+    a: tuple, b_sorted: tuple, a_sorted: bool = False
 ) -> jax.Array:
-    """Membership of a[i] in sorted b (EMPTY-padded b allowed at the end);
-    ``a_sorted`` is accepted for parity with the kernel and unused."""
-    idx = jnp.searchsorted(b_sorted, a)
-    idx = jnp.minimum(idx, b_sorted.shape[0] - 1)
-    return b_sorted[idx] == a
+    """Membership of each key of ``a`` in the sorted keys ``b_sorted``
+    (BIG-padded at the end allowed); keys are tuples of int32 words
+    (:func:`pack_pairs`).  ``a_sorted`` is accepted for parity with the
+    kernel and unused."""
+    from ..kernels.sorted_member import searchsorted_words
+
+    idx = searchsorted_words(b_sorted, a)
+    idx = jnp.minimum(idx, b_sorted[0].shape[0] - 1)
+    hit = b_sorted[0][idx] == a[0]
+    for b, x in zip(b_sorted[1:], a[1:]):
+        hit = hit & (b[idx] == x)
+    return hit
 
 
 def sorted_member_kernel(
-    a: jax.Array, b_sorted: jax.Array, a_sorted: bool = False
+    a: tuple, b_sorted: tuple, a_sorted: bool = False
 ) -> jax.Array:
     """Pallas-kernel membership (``repro.kernels.sorted_member``) — the
-    TPU device path for the dedup anti-join.  ``interpret`` is backend-
-    detected (interpret on CPU, compiled on TPU — see
-    ``repro.kernels.backend``)."""
+    TPU device path for the dedup anti-join; two-word keys take the
+    two-word kernel.  ``interpret`` is backend-detected (interpret on
+    CPU, compiled on TPU — see ``repro.kernels.backend``)."""
     from ..kernels import ops
 
+    if len(a) == 1:
+        a, b_sorted = a[0], b_sorted[0]
     return ops.member(a, b_sorted, a_sorted=a_sorted, interpret=None)
 
 
-#: x64 is disabled by default in JAX, so packed fact keys live in int32:
-#: binary facts use 15/16-bit halves, constraining the *distributed* path
-#: to dictionaries of < 32768 constants (the host engine keeps full int64).
-MAX_DIST_CONST = 1 << 15
+#: x64 is disabled by default in JAX, so fact keys are int32 words.  A
+#: binary fact's key is one word, both ids in 16-bit halves, while every
+#: id the engine has seen is below ONE_WORD_IDS, and two words, the ids
+#: themselves compared lexicographically, once one is not.  Either way
+#: ids lie in [0, MAX_DIST_CONST), below BIG, the sentinel of every sort.
+ONE_WORD_IDS = 1 << 15
+MAX_DIST_CONST = int(np.iinfo(np.int32).max)
 BIG = jnp.int32(np.iinfo(np.int32).max)
 
 
-def pack_pairs(rows: jax.Array) -> jax.Array:
-    """Pack (n, 2) int32 rows into sortable int32 keys; (n, 1) passes through."""
+def key_words(max_id: int) -> int:
+    """Words of a binary fact's key when no id exceeds ``max_id``."""
+    return 1 if max_id < ONE_WORD_IDS else 2
+
+
+def pack_pairs(rows: jax.Array, words: int = 1) -> tuple:
+    """Sortable int32 keys of (n, arity) int32 rows, as a tuple of words:
+    a unary row's id; a binary row's ids in 16-bit halves of one word, or
+    with ``words=2`` the two ids as they are."""
     if rows.shape[1] == 1:
-        return rows[:, 0]
+        return (rows[:, 0],)
     hi = rows[:, 0]
     lo = rows[:, 1]
-    return (hi << 16) | (lo & 0xFFFF)
+    if words == 2:
+        return (hi, lo)
+    return ((hi << 16) | (lo & 0xFFFF),)
 
 
-def unpack_pairs(keys: jax.Array, arity: int) -> jax.Array:
-    if arity == 1:
-        return keys[:, None]
-    hi = keys >> 16
-    lo = jnp.bitwise_and(keys, 0xFFFF)
-    return jnp.stack([hi, lo], axis=1)
+def sort_keys(keys: tuple, valid: jax.Array) -> tuple:
+    """The valid keys sorted lexicographically, each word of the invalid
+    ones BIG (so they sort last)."""
+    masked = tuple(jnp.where(valid, w, BIG) for w in keys)
+    if len(masked) == 1:  # jnp.sort: one-word programs keep their HLO
+        return (jnp.sort(masked[0], stable=False),)
+    return tuple(
+        jax.lax.sort(masked, num_keys=len(masked), is_stable=False)
+    )
+
+
+def _neighbours_differ(ks: tuple) -> jax.Array:
+    """``ks[i + 1] != ks[i]`` over keys of one or more words."""
+    differs = ks[0][1:] != ks[0][:-1]
+    for w in ks[1:]:
+        differs = differs | (w[1:] != w[:-1])
+    return differs
 
 
 def dedup_against(
-    new_keys: jax.Array, new_valid: jax.Array, old_keys_sorted: jax.Array,
-    member_fn=sorted_member_jnp, restrict_sorted: jax.Array | None = None,
+    new_keys: tuple, new_valid: jax.Array, old_keys_sorted: tuple,
+    member_fn=sorted_member_jnp, restrict_sorted: tuple | None = None,
 ) -> jax.Array:
     """Valid-mask of new facts that are not already present in old (and,
-    with ``restrict_sorted``, that are present there).
+    with ``restrict_sorted``, that are present there); keys are tuples
+    of words (:func:`pack_pairs`).
 
     Works on the sorted keys — first occurrences are neighbour compares,
     and the membership probes arrive sorted — then scatters back."""
-    masked = jnp.where(new_valid, new_keys, BIG)
-    ks, order = jax.lax.sort(
-        (masked, jnp.arange(masked.shape[0], dtype=jnp.int32)),
-        num_keys=1, is_stable=False,
+    masked = tuple(jnp.where(new_valid, w, BIG) for w in new_keys)
+    *ks, order = jax.lax.sort(
+        (*masked, jnp.arange(masked[0].shape[0], dtype=jnp.int32)),
+        num_keys=len(masked), is_stable=False,
     )
-    keep = jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]])
-    # valid keys never equal BIG (packed ids stay below it)
-    keep = keep & (ks != BIG) & ~member_fn(ks, old_keys_sorted, a_sorted=True)
+    ks = tuple(ks)
+    keep = jnp.concatenate([jnp.ones((1,), bool), _neighbours_differ(ks)])
+    # valid keys never hold BIG in their first word (ids stay below it)
+    keep = keep & (ks[0] != BIG) & ~member_fn(ks, old_keys_sorted, a_sorted=True)
     if restrict_sorted is not None:
         keep = keep & member_fn(ks, restrict_sorted, a_sorted=True)
     return jnp.zeros_like(keep).at[order].set(keep)
@@ -339,6 +376,16 @@ class DistributedEngine:
         self._mirror: dict[str, tuple[np.ndarray, np.ndarray]] = {}
         self._preds: tuple[str, ...] = ()
         self._arities: dict[str, int] = {}
+        #: words of a binary fact's key (:func:`key_words`), from the
+        #: largest id seen in facts and in the program's constants; part
+        #: of every variant's key
+        self._key_words = 1
+        #: the largest constant the rules emit or match on device
+        self._rule_top = max(
+            (t for rule in program for atom in (rule.head, *rule.body)
+             for t in atom.terms if isinstance(t, int)),
+            default=0,
+        )
         #: global row count per predicate (the mirror's cnt summed)
         self._counts: dict[str, int] = {}
         #: host-side explicit fact set (int64 rows; the apply() contract)
@@ -441,8 +488,8 @@ class DistributedEngine:
 
     @staticmethod
     def _check_const_range(pred: str, rows: np.ndarray) -> None:
-        """Load-bearing for pack_pairs/BIG-sentinel correctness:
-        out-of-range ids would silently corrupt packed join/dedup keys."""
+        """Load-bearing for the BIG sentinel and the int32 state: an id
+        outside ``[0, MAX_DIST_CONST)`` would silently corrupt keys."""
         if rows.size and (
             int(rows.min()) < 0 or int(rows.max()) >= MAX_DIST_CONST
         ):
@@ -451,6 +498,16 @@ class DistributedEngine:
                 f"[0, {MAX_DIST_CONST}) — {pred!r} has values in "
                 f"[{int(rows.min())}, {int(rows.max())}]"
             )
+
+    def _see_ids(self, *batches) -> None:
+        """Widen the keys to two words once an id of ``batches`` (dicts
+        of range-checked rows) needs them; never narrows them."""
+        top = max(
+            (int(np.asarray(r).max()) for batch in batches
+             for r in batch.values() if np.asarray(r).size),
+            default=0,
+        )
+        self._key_words = max(self._key_words, key_words(top))
 
     def _flat_state(self) -> list:
         out = []
@@ -738,11 +795,15 @@ class DistributedEngine:
             plan.joins[0].partition_key if plan.joins else None,
         )
 
+    def _layout(self) -> tuple:
+        """The predicate tuple (the buffer layout) and the key words: a
+        variant's key holds both, so the variant cache survives
+        re-materialisation over the same schema (warm fixpoints time
+        rounds, not re-tracing), and wider ids select other variants."""
+        return (self._preds, self._key_words)
+
     def _pair_key(self, pairs) -> tuple:
-        # the predicate tuple keys the buffer layout, so the variant
-        # cache survives re-materialisation over the same schema
-        # (warm fixpoints time rounds, not re-tracing)
-        return (self._preds,) + tuple(
+        return (self._layout(),) + tuple(
             self._plan_signature(r, pl) for r, _pv, pl in pairs
         )
 
@@ -779,22 +840,21 @@ class DistributedEngine:
         the CPU tests exactly as it does on the chip."""
         return tuple(range(3 * len(self._preds)))
 
-    def _merge_block(self, trows, tcnt, rows, valid, restrict=None):
+    def _merge_block(self, trows, tcnt, rows, valid, restrict=None, words=1):
         """Dedup candidate rows against a target buffer (and optionally
         restrict them to a membership set), then append — the shared
-        tail of every round/seed.  Returns (rows', cnt', fresh, overflow)."""
+        tail of every round/seed; keys of ``words`` int32 words.
+        Returns (rows', cnt', fresh, overflow)."""
         cap = trows.shape[0]
-        keys = pack_pairs(rows)
-        tvalid = jnp.arange(cap) < tcnt
-        tsorted = jnp.sort(
-            jnp.where(tvalid, pack_pairs(trows), BIG), stable=False
-        )
-        rsorted = None
-        if restrict is not None:
-            rrows, rcnt = restrict
-            rsorted = jnp.sort(jnp.where(
-                jnp.arange(rrows.shape[0]) < rcnt, pack_pairs(rrows), BIG
-            ), stable=False)
+        with jax.named_scope("keys"):
+            keys = pack_pairs(rows, words)
+            tvalid = jnp.arange(cap) < tcnt
+            tsorted = sort_keys(pack_pairs(trows, words), tvalid)
+            rsorted = None
+            if restrict is not None:
+                rrows, rcnt = restrict
+                rvalid = jnp.arange(rrows.shape[0]) < rcnt
+                rsorted = sort_keys(pack_pairs(rrows, words), rvalid)
         with jax.named_scope("dedup"):
             fresh = dedup_against(
                 keys, valid, tsorted, member_fn=self._member_fn,
@@ -826,7 +886,7 @@ class DistributedEngine:
         ``union_acc`` the accumulator is unioned into old/all reads, and
         ``use_restrict`` keeps only candidates inside a membership set).
         """
-        preds, axis = self._preds, self.axis
+        preds, axis, words = self._preds, self.axis, self._key_words
 
         def body(*flat):
             k = 0
@@ -917,6 +977,7 @@ class DistributedEngine:
                     nrows, ncnt, n_fresh, of = self._merge_block(
                         trows, tcnt, rows, valid,
                         restrict=restrict.get(pred) if use_restrict else None,
+                        words=words,
                     )
                 total_new = total_new + n_fresh
                 overflow = overflow + of
@@ -953,7 +1014,7 @@ class DistributedEngine:
     def _build_delete(self):
         """Per-shard deletion: drop routed rows from every predicate's
         buffer and compact survivors to the front (delta emptied)."""
-        preds = self._preds
+        preds, words = self._preds, self._key_words
         member_fn = self._member_fn
 
         def body(*flat):
@@ -973,10 +1034,12 @@ class DistributedEngine:
                 cap = rows.shape[0]
                 idx = jnp.arange(cap)
                 slot = idx < cnt
-                keys = jnp.where(slot, pack_pairs(rows), BIG)
-                dsorted = jnp.sort(jnp.where(
-                    jnp.arange(drows.shape[0]) < dcnt, pack_pairs(drows), BIG
-                ), stable=False)
+                with jax.named_scope("keys"):
+                    keys = tuple(
+                        jnp.where(slot, w, BIG) for w in pack_pairs(rows, words)
+                    )
+                    dvalid = jnp.arange(drows.shape[0]) < dcnt
+                    dsorted = sort_keys(pack_pairs(drows, words), dvalid)
                 keep = slot & ~member_fn(keys, dsorted)
                 n_keep = jnp.sum(keep.astype(jnp.int32))
                 perm = jnp.argsort(
@@ -1002,7 +1065,7 @@ class DistributedEngine:
     def _build_merge(self):
         """Per-shard seed/fold-in: dedup routed host rows against each
         predicate's buffer and append them as the new delta."""
-        preds, axis = self._preds, self.axis
+        preds, axis, words = self._preds, self.axis, self._key_words
 
         def body(*flat):
             k = 0
@@ -1023,7 +1086,7 @@ class DistributedEngine:
                 avalid = jnp.arange(arows.shape[0]) < acnt
                 with jax.named_scope("merge"):
                     nrows, ncnt, n_fresh, of = self._merge_block(
-                        rows, cnt, arows, avalid
+                        rows, cnt, arows, avalid, words=words
                     )
                 total_new = total_new + n_fresh
                 overflow = overflow + of
@@ -1329,9 +1392,12 @@ class DistributedEngine:
             )
             if rows.ndim == 1:
                 rows = rows.reshape(-1, 1)
+            self._check_const_range(p, rows)
             full[p] = unique_rows(rows) if rows.shape[0] else rows
         self._preds = preds
         self._arities = arities
+        self._key_words = key_words(self._rule_top)
+        self._see_ids(full)
         self.explicit = {
             p: rows for p, rows in full.items() if rows.shape[0]
         }
@@ -1368,7 +1434,8 @@ class DistributedEngine:
                     else [list(self.program)]
                 )
             self.stats.n_strata = len(strata)
-            sp.set(n_strata=len(strata))
+            self.stats.key_words = self._key_words
+            sp.set(n_strata=len(strata), key_words=self._key_words)
             rounds = 0
             for si, stratum in enumerate(strata):
                 used, converged = self._stratum_fixpoint(
@@ -1513,6 +1580,8 @@ class DistributedEngine:
         for batch in (adds, dels):
             for pred, rows in batch.items():
                 self._check_const_range(pred, rows)
+        self._see_ids(adds, dels)
+        st.key_words = self._key_words
         # E := E \ D, swept before the additions clamp (same phase order
         # as IncrementalStore.apply)
         self._dirty = True
@@ -1583,7 +1652,7 @@ class DistributedEngine:
                 flat.extend(routed[p])
             with span("dist.launch"):
                 rec = self._variant(
-                    ("delete", self._preds), self._build_delete
+                    ("delete", self._layout()), self._build_delete
                 )
                 out = rec.fn(*flat)
             with span("dist.sync"):
@@ -1642,7 +1711,7 @@ class DistributedEngine:
         for p in self._preds:
             flat.extend(routed[p])
         with span("dist.launch"):
-            rec = self._variant(("merge", self._preds), self._build_merge)
+            rec = self._variant(("merge", self._layout()), self._build_merge)
             out = rec.fn(*flat)
         with span("dist.wait"):
             block = self._fetch(out[-1])

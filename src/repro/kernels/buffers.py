@@ -10,8 +10,9 @@ that shape for every engine:
   take either), but survivors are folded in with the positional
   ``merge_sorted_unique_np`` instead of a full re-sort per round.
 * **device mode** (``device=True``) — facts are sorted-unique packed
-  **int32** codes (the 16-bit-halves pack of
-  ``core.distributed.pack_pairs``) in ``BIG``-padded device buffers of
+  **int32** codes (the 16-bit-halves pack of the one-word keys of
+  ``core.distributed.pack_pairs``, ids below ``2**15``; there is no
+  two-word form here) in ``BIG``-padded device buffers of
   power-of-two capacity, with a host-tracked ``count`` watermark.
   Each round's fresh codes are folded in by the ``merge_sorted_unique``
   Pallas kernel with the buffer **donated**

@@ -18,10 +18,11 @@ device:
   (``input_output_aliases`` + a donating jit variant), so steady-state
   rounds reuse the same device allocation (see :mod:`.buffers`).
 
-Value contract (identical to ``core.distributed.pack_pairs``): all ids
-are non-negative int32; packed pairs are ``(hi << 16) | (lo & 0xffff)``
-with the high half below ``2**15``, so every packed code is in
-``[0, 2**31)`` and :data:`BIG` (int32 max) is a safe pad sentinel.
+Value contract (the one-word keys of ``core.distributed.pack_pairs``;
+these kernels have no two-word form): all ids are non-negative int32
+below ``2**15``; packed pairs are ``(hi << 16) | (lo & 0xffff)``, so
+every packed code is in ``[0, 2**31)`` and :data:`BIG` (int32 max) is a
+safe pad sentinel.
 
 Both kernels are single-program launches holding their operands in VMEM
 (the pair-enumeration broadcast is O(capacity x n_left)); callers cap

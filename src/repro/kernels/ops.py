@@ -21,6 +21,7 @@ from .fused import merge_sorted_unique as _merge_sorted_unique
 from .join_bounds import join_bounds as _join_bounds
 from .rle_expand import rle_expand as _rle_expand
 from .sorted_member import sorted_member as _sorted_member
+from .sorted_member import sorted_member_wide as _sorted_member_wide
 from .tune import get_blocks
 
 __all__ = [
@@ -96,10 +97,21 @@ def _blocks_for(kernel: str, n: int, interpret, blocks: dict) -> dict:
 def member(a, b_sorted, *, a_sorted: bool = False,
            interpret: bool | None = None, **blocks) -> jax.Array:
     """``out[i] = a[i] in b_sorted`` (semi-join filter); ``a_sorted``
-    promises ascending probes (see :func:`.sorted_member.sorted_member`)."""
+    promises ascending probes (see :func:`.sorted_member.sorted_member`).
+    Keys of two int32 words come as ``(high, low)`` tuples for both
+    ``a`` and ``b_sorted`` (see
+    :func:`.sorted_member.sorted_member_wide`)."""
+    interpret = resolve_interpret(interpret)
+    if isinstance(a, tuple):
+        a = tuple(jnp.asarray(w) for w in a)
+        _metered("member_wide", a[0].size, a[0])
+        blocks = _blocks_for("sorted_member_wide", a[0].size, interpret, blocks)
+        return _sorted_member_wide(
+            a, tuple(jnp.asarray(w) for w in b_sorted), a_sorted=a_sorted,
+            interpret=interpret, **blocks,
+        )
     a = jnp.asarray(a)
     _metered("member", a.size, a)
-    interpret = resolve_interpret(interpret)
     blocks = _blocks_for("sorted_member", a.size, interpret, blocks)
     return _sorted_member(
         a, jnp.asarray(b_sorted), a_sorted=a_sorted, interpret=interpret,

@@ -1,10 +1,10 @@
 """Block-size autotuner for the Pallas kernels.
 
-The blocked kernels (``sorted_member``, ``join_bounds``, ``rle_expand``)
-take ``block_*`` sizes that trade VMEM residency against grid overhead;
-the right choice depends on the backend and the operand size.  This
-module picks them per ``(kernel, dtype, size-bucket)`` from a one-shot
-timing sweep:
+The blocked kernels (``sorted_member``, ``sorted_member_wide``,
+``join_bounds``, ``rle_expand``) take ``block_*`` sizes that trade VMEM
+residency against grid overhead; the right choice depends on the backend
+and the operand size.  This module picks them per ``(kernel, dtype,
+size-bucket)`` from a one-shot timing sweep:
 
 * **buckets** — operand sizes are bucketed to the next power of two
   (floor 256), so one sweep covers every size in the bucket and the
@@ -60,6 +60,7 @@ CACHE_VERSION = 2
 #: sweep in interpret mode
 DEFAULTS: dict[str, dict[str, int]] = {
     "sorted_member": {"block_a": 1024, "block_b": 1024},
+    "sorted_member_wide": {"block_a": 1024, "block_b": 1024},
     "join_bounds": {"block_l": 1024, "block_r": 1024},
     "rle_expand": {"block_out": 1024},
 }
@@ -67,6 +68,9 @@ DEFAULTS: dict[str, dict[str, int]] = {
 #: candidate assignments swept per kernel (defaults always included)
 CANDIDATES: dict[str, list[dict[str, int]]] = {
     "sorted_member": [
+        {"block_a": a, "block_b": b} for a in (1024, 2048) for b in (1024, 2048)
+    ],
+    "sorted_member_wide": [
         {"block_a": a, "block_b": b} for a in (1024, 2048) for b in (1024, 2048)
     ],
     "join_bounds": [
@@ -157,6 +161,16 @@ def _runner(kernel: str, bucket: int, blocks: dict[str, int], interpret: bool):
         a = jnp.arange(bucket, dtype=jnp.int32) * 3
         b = jnp.arange(bucket, dtype=jnp.int32) * 2
         out = sorted_member(a, b, a_sorted=True, interpret=interpret, **blocks)
+    elif kernel == "sorted_member_wide":
+        from .sorted_member import sorted_member_wide
+
+        # keys (i // 4, i % 4): ties in the high word, the low word decides
+        i = jnp.arange(bucket, dtype=jnp.int32)
+        a = (i * 3 // 4, i * 3 % 4)
+        b = (i * 2 // 4, i * 2 % 4)
+        out = sorted_member_wide(
+            a, b, a_sorted=True, interpret=interpret, **blocks
+        )
     elif kernel == "join_bounds":
         from .join_bounds import join_bounds
 

@@ -117,8 +117,9 @@ DISTRIBUTED_COUNTERS = (
 )
 
 #: DistributedStats levels the engine sets (``n_facts``/``n_meta_facts``
-#: and the per-phase times of the host engines are never set there)
-DISTRIBUTED_GAUGES = ("n_strata", "epoch")
+#: and the per-phase times of the host engines are never set there);
+#: ``key_words`` is set once per materialise and per apply
+DISTRIBUTED_GAUGES = ("n_strata", "epoch", "key_words")
 
 
 def _publish_rule_scope(reg: MetricsRegistry, stats) -> None:

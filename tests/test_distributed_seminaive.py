@@ -195,18 +195,20 @@ def test_exchange_regrow_instead_of_abort():
 
 
 def test_constants_out_of_packing_range_are_rejected():
-    """pack_pairs keys are 15/16-bit halves; ids >= MAX_DIST_CONST (or
-    negative rows) must raise instead of silently corrupting joins."""
+    """Ids live in int32 below BIG, the sort sentinel: a negative id, or
+    one at or past ``MAX_DIST_CONST`` (int32 max), must raise instead of
+    silently corrupting joins."""
     program, dataset, _ = chain(5)
-    eng = DistributedEngine(program, make_mesh(), capacity=1 << 9)
-    bad = dict(dataset)
-    bad["edge"] = np.asarray([[40000, 1]], np.int64)
-    with pytest.raises(ValueError, match="constants"):
-        eng.materialise(bad)
-    eng2 = DistributedEngine(program, make_mesh(), capacity=1 << 9)
-    eng2.materialise(dataset)
-    with pytest.raises(ValueError, match="constants"):
-        eng2.apply(additions={"edge": np.asarray([[1, 40000]], np.int64)})
+    for bad_id in (-1, 2**31 - 1):
+        eng = DistributedEngine(program, make_mesh(), capacity=1 << 9)
+        bad = dict(dataset)
+        bad["edge"] = np.asarray([[bad_id, 1]], np.int64)
+        with pytest.raises(ValueError, match="constants"):
+            eng.materialise(bad)
+        eng2 = DistributedEngine(program, make_mesh(), capacity=1 << 9)
+        eng2.materialise(dataset)
+        with pytest.raises(ValueError, match="constants"):
+            eng2.apply(additions={"edge": np.asarray([[1, bad_id]], np.int64)})
 
 
 # --------------------------------------------------------------------- #

@@ -3,6 +3,7 @@
 All kernels run in interpret=True mode (CPU container; TPU is the target).
 """
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -65,6 +66,93 @@ class TestSortedMember:
         got = np.asarray(ops.member(a, b, block_a=64, block_b=64))
         want = np.isin(a, b)
         assert_array_equal(got, want)
+
+
+BIG = np.iinfo(np.int32).max
+
+
+def _wide_table(rng, m, hi_range, lo_range=2**31 - 1):
+    """``m`` two-word keys sorted lexicographically, high words from a
+    small range so that many keys tie in it."""
+    hi = rng.integers(0, hi_range, size=m).astype(np.int32)
+    lo = rng.integers(0, lo_range, size=m).astype(np.int32)
+    order = np.lexsort((lo, hi))
+    return hi[order], lo[order]
+
+
+def _wide_oracle(a, b):
+    table = set(zip(b[0].tolist(), b[1].tolist()))
+    return np.asarray([k in table for k in zip(a[0].tolist(), a[1].tolist())],
+                      dtype=bool)
+
+
+class TestSortedMemberWide:
+    """The two-word kernel against the engine's jnp two-word membership
+    (and a set), in interpret mode."""
+
+    @staticmethod
+    def _probes(rng, b, n):
+        """Half hits drawn from the table, half near misses that share a
+        table key's high word."""
+        pick = rng.integers(0, len(b[0]), size=n)
+        hi, lo = b[0][pick].copy(), b[1][pick].copy()
+        miss = rng.random(n) < 0.5
+        lo[miss] = rng.integers(0, 2**31 - 1, size=int(miss.sum()))
+        return hi, lo
+
+    def _check(self, a, b, **kw):
+        from repro.core.distributed import sorted_member_jnp
+
+        got = np.asarray(ops.member(a, b, **kw))
+        assert_array_equal(got, _wide_oracle(a, b))
+        want = np.asarray(sorted_member_jnp(
+            tuple(map(jnp.asarray, a)), tuple(map(jnp.asarray, b))
+        ))
+        assert_array_equal(got, want)
+        return got
+
+    @pytest.mark.parametrize("n,m", [(1, 1), (7, 3), (300, 450), (1024, 17),
+                                     (2049, 513)])
+    def test_ties_in_the_high_word(self, n, m):
+        rng = np.random.default_rng(n * 7 + m)
+        b = _wide_table(rng, m, hi_range=4)
+        a = self._probes(rng, b, n)
+        got = self._check(a, b)
+        assert got.any() or n < 4
+
+    @pytest.mark.parametrize("block_a,block_b", [(8, 16), (128, 128)])
+    def test_sorted_probes_across_blocks(self, block_a, block_b):
+        rng = np.random.default_rng(1)
+        b = _wide_table(rng, 3000, hi_range=3, lo_range=5000)
+        a = self._probes(rng, b, 2500)
+        order = np.lexsort((a[1], a[0]))
+        a = (a[0][order], a[1][order])
+        self._check(a, b, a_sorted=True, block_a=block_a, block_b=block_b)
+
+    def test_unsorted_probes(self):
+        rng = np.random.default_rng(2)
+        b = _wide_table(rng, 700, hi_range=2**31 - 1)
+        a = self._probes(rng, b, 900)
+        assert (np.diff(a[0].astype(np.int64)) < 0).any()  # not sorted
+        self._check(a, b, block_a=128, block_b=128)
+
+    def test_probes_at_the_sentinel(self):
+        """A BIG-padded table (the engine's invalid rows) and probes at the
+        sentinel in either word: the kernel answers as the jnp path."""
+        rng = np.random.default_rng(3)
+        hi, lo = _wide_table(rng, 200, hi_range=5)
+        b = (np.concatenate([hi, np.full(56, BIG, np.int32)]),
+             np.concatenate([lo, np.full(56, BIG, np.int32)]))
+        a = (np.concatenate([hi[:10], [BIG, BIG, 3]]).astype(np.int32),
+             np.concatenate([lo[:10], [BIG, 0, BIG]]).astype(np.int32))
+        got = self._check(a, b)
+        assert got[:10].all() and got[10] and not got[11:].any()
+
+    def test_empty_table(self):
+        a = (np.arange(5, dtype=np.int32), np.arange(5, dtype=np.int32))
+        empty = (np.zeros(0, np.int32), np.zeros(0, np.int32))
+        assert not np.asarray(ops.member(a, empty)).any()
+        assert np.asarray(ops.member((a[0][:0], a[1][:0]), a)).shape == (0,)
 
 
 class TestRleExpand:

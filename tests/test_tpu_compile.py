@@ -69,6 +69,18 @@ def test_sorted_member_compiles(one_chip, a_sorted):
     _assert_mosaic(compiled)
 
 
+@pytest.mark.parametrize("a_sorted", [False, True])
+def test_sorted_member_wide_compiles(one_chip, a_sorted):
+    from repro.kernels.sorted_member import _sorted_member_wide_jit
+
+    probes, table = _i32(8192, one_chip), _i32(1 << 19, one_chip)
+    compiled = _sorted_member_wide_jit.lower(
+        probes, probes, table, table,
+        rows_a=8, rows_b=8, a_sorted=a_sorted, interpret=False,
+    ).compile()
+    _assert_mosaic(compiled)
+
+
 def test_join_bounds_compiles(one_chip):
     from repro.kernels.join_bounds import _join_bounds_jit
 
